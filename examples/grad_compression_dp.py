@@ -49,7 +49,7 @@ def main() -> None:
     @functools.partial(
         shard_map, mesh=mesh,
         in_specs=(P(), P(("pod", "data"), None)),
-        out_specs=(P(), P()), check_rep=False)
+        out_specs=(P(), P()), check_vma=False)
     def sync_grads(w, x):
         g = local_grad(w, x)
         # fast in-pod reduce (ICI): fp32
@@ -68,7 +68,7 @@ def main() -> None:
     @functools.partial(
         shard_map, mesh=mesh,
         in_specs=(P(), P(("pod", "data"), None)),
-        out_specs=P(), check_rep=False)
+        out_specs=P(), check_vma=False)
     def sync_grads_fp32(w, x):
         return jax.lax.pmean(local_grad(w, x), ("pod", "data"))
 

@@ -17,9 +17,10 @@ import jax
 
 from repro.compat import xla as cxla
 from repro.core import DoRAConfig
+from repro.launch.dryrun import MODELED_DEVICE_KIND
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import StepConfig, cell_specs
-from repro.roofline import analyze_hlo_text, roofline_terms
+from repro.roofline import analyze_hlo_text, hw_for, roofline_terms
 
 
 def main() -> None:
@@ -53,11 +54,11 @@ def main() -> None:
         with open(args.dump_hlo, "w") as f:
             f.write(hlo)
     ana = analyze_hlo_text(hlo)
-    terms = roofline_terms(ana)
+    terms = roofline_terms(ana, hw_for(MODELED_DEVICE_KIND))
     mem = compiled.memory_analysis()
     print(f"== {args.arch} x {args.shape} "
           f"({'2x16x16' if args.multi_pod else '16x16'}) "
-          f"norm={args.norm_impl} ==")
+          f"norm={args.norm_impl}, modeling {MODELED_DEVICE_KIND} ==")
     print(f"compute {terms['compute_s']*1e3:.1f} ms | memory "
           f"{terms['memory_s']*1e3:.1f} ms | collective "
           f"{terms['collective_s']*1e3:.1f} ms -> {terms['dominant']}")

@@ -1,22 +1,16 @@
-"""Version-portability shim layer.
+"""The one place each drifting JAX API is named.
 
-The repo targets a Pallas/JAX API surface that drifts across releases
-(``jax.tree.flatten_with_path``, ``pltpu.CompilerParams`` vs
-``TPUCompilerParams``, ``jax.make_mesh(axis_types=...)``, ``jax.shard_map``).
-Every module under ``src/repro`` goes through this package instead of calling
-those APIs directly, so a JAX upgrade (or downgrade) is absorbed in exactly
-one place:
+Every module under ``src/repro`` goes through this package instead of
+calling these APIs directly, so a JAX upgrade is absorbed here. The
+package targets the installed JAX (0.9.0) and carries no branch for an
+older release:
 
   - :mod:`repro.compat.tree`   — pytree utilities with path support
-  - :mod:`repro.compat.pallas` — Pallas TPU/GPU compiler-params + scratch
+  - :mod:`repro.compat.pallas` — the Pallas modules and the interpret switch
   - :mod:`repro.compat.mesh`   — mesh construction / shard_map entry points
-  - :mod:`repro.compat.probes` — dtype/device/backend capability probes
+  - :mod:`repro.compat.probes` — backend probes (a failed backend raises)
   - :mod:`repro.compat.xla`    — compiled-artifact introspection (memory /
     cost analysis)
-
-Policy: shims prefer the NEW API name when present and fall back to the old
-one; they never silently change numerics — anything that cannot be expressed
-on the installed version raises with the probe's reason string.
 """
 from repro.compat import mesh, pallas, probes, tree, xla
 

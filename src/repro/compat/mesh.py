@@ -1,61 +1,28 @@
-"""Mesh construction and shard_map entry points, portable across JAX.
+"""Mesh construction and shard_map entry points.
 
-Newer JAX grew ``jax.make_mesh(..., axis_types=AxisType.Auto)`` and promoted
-``shard_map`` to ``jax.shard_map``; older releases have neither ``AxisType``
-nor the promoted name (``jax.experimental.shard_map.shard_map``). The repo's
-meshes are always fully "auto" (GSPMD derives the collectives), which is
-exactly the old default — so on old JAX the axis-type argument is simply
-omitted, with identical partitioning semantics.
+The repo's meshes are always fully "auto" (GSPMD derives the
+collectives), so every mesh is built with ``AxisType.Auto`` axes.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-AxisType = getattr(jax.sharding, "AxisType", None)
-
-
-def _resolve_shard_map():
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn
-    from jax.experimental.shard_map import shard_map as fn  # noqa: F811
-    return fn
-
-
-shard_map = _resolve_shard_map()
+shard_map = jax.shard_map
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with auto axis types wherever expressible."""
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if AxisType is not None:
-        try:
-            return jax.make_mesh(
-                tuple(axis_shapes), tuple(axis_names),
-                axis_types=(AxisType.Auto,) * len(tuple(axis_names)),
-                **kwargs)
-        except TypeError:
-            pass  # AxisType exists but make_mesh predates axis_types
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    """``jax.make_mesh`` with auto axis types."""
+    names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), names,
+                         axis_types=(AxisType.Auto,) * len(names),
+                         devices=devices)
 
 
 def shard_map_unchecked(f, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off, across the kwarg rename.
+    """``shard_map`` with the varying-manual-axes check off.
 
     The kernel wrappers run Pallas calls inside the mapped body; the
-    replication checker has no rule for them, so checking must be
-    disabled. The kwarg that disables it was renamed ``check_rep`` →
-    ``check_vma`` across JAX releases — try both, and fall back to the
-    bare call (newest JAX drops the kwarg once sharding-in-types lands).
-    """
-    for kw in ({"check_rep": False}, {"check_vma": False}, {}):
-        try:
-            return shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-        except TypeError as e:
-            if kw and next(iter(kw)) in str(e):
-                continue
-            raise
-    raise AssertionError("unreachable")  # pragma: no cover
+    checker has no rule for them, so checking must be disabled."""
+    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                     check_vma=False)

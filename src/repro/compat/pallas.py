@@ -1,99 +1,21 @@
-"""Pallas construction shims (compiler params, scratch memory spaces).
+"""Pallas entry points: the ``pl`` / ``pltpu`` modules and the interpret
+switch.
 
-Two API drifts are absorbed here:
-
-  - the TPU compiler-params class was renamed ``TPUCompilerParams`` →
-    ``CompilerParams`` across Pallas releases; :func:`tpu_compiler_params`
-    constructs whichever the installed JAX exposes (dropping kwargs the
-    old signature does not know, which are tuning hints, never semantics);
-  - scratch memory-space constructors (``pltpu.VMEM`` / ``pltpu.SMEM``)
-    live behind the same import gate so a host without the pallas.tpu
-    extension degrades to a clear error only when a kernel actually runs.
-
-``compiler_params=None`` is valid for ``pl.pallas_call`` on every supported
-version (and ignored entirely in interpret mode), so a missing params class
-is non-fatal for CPU validation.
+Kernels build their TPU compiler params as ``pltpu.CompilerParams(...)``
+directly, so an argument the installed Pallas does not know raises at
+trace time instead of being dropped.
 """
 from __future__ import annotations
 
-from typing import Any
+from jax.experimental import pallas as pl  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu  # noqa: F401
 
 from repro.compat import probes
-
-try:  # pragma: no branch
-    from jax.experimental import pallas as pl  # noqa: F401
-except Exception:  # pragma: no cover - pallas-free host
-    pl = None
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover - pallas-free host
-    pltpu = None
-
-
-def _params_cls():
-    """The installed TPU compiler-params class (new name preferred)."""
-    if pltpu is None:
-        return None
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = getattr(pltpu, "TPUCompilerParams", None)
-    return cls
-
-
-def tpu_compiler_params(*, dimension_semantics=None,
-                        **kwargs: Any):
-    """Build TPU compiler params portably; ``None`` when unavailable.
-
-    Unknown kwargs (version-specific tuning knobs like
-    ``vmem_limit_bytes``) are retried without — they affect scheduling,
-    not results, so dropping them on an older JAX is safe.
-    """
-    cls = _params_cls()
-    if cls is None:
-        return None
-    kw = dict(kwargs)
-    if dimension_semantics is not None:
-        kw["dimension_semantics"] = tuple(dimension_semantics)
-    while True:
-        try:
-            return cls(**kw)
-        except TypeError as e:
-            # Drop one unknown kwarg and retry; bail out when none are left
-            # to drop (a genuine signature error should surface).
-            dropped = _drop_unknown_kwarg(kw, e)
-            if not dropped:
-                raise
-
-
-def _drop_unknown_kwarg(kw: dict, err: TypeError) -> bool:
-    msg = str(err)
-    for name in list(kw):
-        if name != "dimension_semantics" and repr(name) in msg:
-            del kw[name]
-            return True
-    return False
 
 
 def resolve_interpret(interpret: bool | None) -> bool:
     """None → probe: interpret mode everywhere except a real TPU backend
     (where Mosaic compiles the kernel)."""
     if interpret is None:
-        return not probes.can_compile_pallas_tpu()
+        return not probes.is_tpu()
     return bool(interpret)
-
-
-def vmem(shape, dtype):
-    """VMEM scratch allocation spec (``scratch_shapes=[vmem(...)]``)."""
-    if pltpu is None:
-        raise RuntimeError("VMEM scratch requested but "
-                           + probes.why_unavailable("interpret"))
-    return pltpu.VMEM(tuple(shape), dtype)
-
-
-def smem(shape, dtype):
-    """SMEM scratch allocation spec."""
-    if pltpu is None:
-        raise RuntimeError("SMEM scratch requested but "
-                           + probes.why_unavailable("interpret"))
-    return pltpu.SMEM(tuple(shape), dtype)

@@ -1,103 +1,33 @@
-"""Dtype / device / backend capability probes.
+"""Backend probes the dispatch table keys on.
 
-Cheap, cached predicates the dispatch table keys on. Probes never raise:
-a missing module or an un-initializable backend reads as "capability
-absent", and :func:`why_unavailable` carries the reason string for error
-messages ("tier 'tpu' forced but unavailable: ...").
+A backend that fails to start raises here: a host without a working TPU
+backend must never read as "cpu" and quietly run the Pallas interpreter
+or the eager tier in place of the compiled kernels. :func:`why_unavailable`
+carries the reason string for error messages ("tier 'tpu' forced but
+unavailable: ...").
 """
 from __future__ import annotations
 
 import functools
 
 import jax
-import jax.numpy as jnp
 
 
 @functools.lru_cache(maxsize=None)
 def backend_platform() -> str:
     """The default JAX backend platform ("cpu" | "tpu" | "gpu")."""
-    try:
-        return jax.default_backend()
-    except Exception:  # pragma: no cover - no backend at all
-        return "cpu"
+    return jax.default_backend()
 
 
 def is_tpu() -> bool:
-    return backend_platform() == "tpu"
-
-
-def is_cpu_only() -> bool:
-    return backend_platform() == "cpu"
-
-
-@functools.lru_cache(maxsize=None)
-def device_kind() -> str:
-    """Marketing name of device 0 ("TPU v5e", "cpu", ...)."""
-    try:
-        return jax.devices()[0].device_kind
-    except Exception:  # pragma: no cover
-        return "unknown"
-
-
-@functools.lru_cache(maxsize=None)
-def has_pallas() -> bool:
-    """Pallas importable at all (interpret mode runs anywhere it is)."""
-    try:
-        from jax.experimental import pallas  # noqa: F401
-        return True
-    except Exception:
-        return False
-
-
-@functools.lru_cache(maxsize=None)
-def has_pallas_tpu() -> bool:
-    """The pallas.tpu extension importable (compiler params, VMEM, ...)."""
-    try:
-        from jax.experimental.pallas import tpu  # noqa: F401
-        return True
-    except Exception:
-        return False
-
-
-def can_compile_pallas_tpu() -> bool:
     """True when Pallas kernels can be *compiled* (Mosaic), i.e. the host
-    actually has a TPU backend — interpret mode does not need this."""
-    return has_pallas_tpu() and is_tpu()
-
-
-@functools.lru_cache(maxsize=None)
-def supports_x64() -> bool:
-    """fp64 arrays representable under the current jax_enable_x64 setting."""
-    try:
-        return jnp.zeros((), jnp.float64).dtype == jnp.float64
-    except Exception:
-        return False
-
-
-@functools.lru_cache(maxsize=None)
-def supports_dtype(dtype) -> bool:
-    """Can the default backend materialize arrays of ``dtype``?"""
-    try:
-        jnp.zeros((1,), dtype).block_until_ready()
-        return True
-    except Exception:
-        return False
+    has a TPU backend — interpret mode does not need this."""
+    return backend_platform() == "tpu"
 
 
 def why_unavailable(tier_name: str) -> str:
     """Human-readable reason a kernel tier cannot run on this host."""
     if tier_name == "tpu":
-        if not has_pallas_tpu():
-            return "jax.experimental.pallas.tpu is not importable"
         return (f"backend is {backend_platform()!r}, not 'tpu' "
                 f"(Mosaic compilation needs a TPU)")
-    if tier_name == "interpret":
-        return "jax.experimental.pallas is not importable"
-    return "eager tier is always available"
-
-
-def clear_probe_caches() -> None:
-    """Reset every cached probe (tests monkeypatch backends)."""
-    for fn in (backend_platform, device_kind, has_pallas, has_pallas_tpu,
-               supports_x64, supports_dtype):
-        fn.cache_clear()
+    return f"tier {tier_name!r} is always available"

@@ -1,40 +1,24 @@
-"""Pytree utilities with path support, portable across JAX versions.
+"""Pytree utilities with path support.
 
-``jax.tree.flatten_with_path`` / ``jax.tree.map_with_path`` only exist on
-newer JAX; older releases spell them ``jax.tree_util.tree_flatten_with_path``
-/ ``tree_map_with_path``. The non-path helpers (``map``, ``flatten``, ...)
-are re-exported too so callers depend on ONE tree API regardless of where
-the installed JAX puts it.
-
-Path entries are the standard ``DictKey``/``SequenceKey``/``GetAttrKey``
-objects on every supported version; :func:`path_key` and :func:`path_str`
-normalize them to plain strings (checkpoint manifests, optimizer masks).
+Re-exports the ``jax.tree`` helpers so callers depend on ONE tree API,
+plus :func:`path_key` and :func:`path_str`, which normalize the standard
+``DictKey``/``SequenceKey``/``GetAttrKey`` path entries to plain strings
+(checkpoint manifests, optimizer masks).
 """
 from __future__ import annotations
 
-from typing import Any, Callable
+from typing import Any
 
 import jax
 
-_tree = getattr(jax, "tree", None)
-_tu = jax.tree_util
-
-
-def _resolve(new_name: str, old_name: str) -> Callable:
-    fn = getattr(_tree, new_name, None) if _tree is not None else None
-    if fn is not None:
-        return fn
-    return getattr(_tu, old_name)
-
-
-flatten = _resolve("flatten", "tree_flatten")
-unflatten = _resolve("unflatten", "tree_unflatten")
-leaves = _resolve("leaves", "tree_leaves")
-structure = _resolve("structure", "tree_structure")
-map = _resolve("map", "tree_map")  # noqa: A001 - mirrors jax.tree.map
-flatten_with_path = _resolve("flatten_with_path", "tree_flatten_with_path")
-map_with_path = _resolve("map_with_path", "tree_map_with_path")
-leaves_with_path = _resolve("leaves_with_path", "tree_leaves_with_path")
+flatten = jax.tree.flatten
+unflatten = jax.tree.unflatten
+leaves = jax.tree.leaves
+structure = jax.tree.structure
+map = jax.tree.map  # noqa: A001 - mirrors jax.tree.map
+flatten_with_path = jax.tree.flatten_with_path
+map_with_path = jax.tree.map_with_path
+leaves_with_path = jax.tree.leaves_with_path
 
 
 def path_key(entry: Any) -> str:

@@ -1,49 +1,15 @@
-"""Compiled-artifact introspection shims.
-
-``compiled.memory_analysis().peak_memory_in_bytes`` only exists on newer
-jaxlib; older CompiledMemoryStats exposes the component sizes instead.
-The fallback reconstructs the device-memory peak the way the allocator
-accounts it: temp (activations/workspace) + arguments + outputs, minus
-donated/aliased buffers counted twice.
-"""
+"""Compiled-artifact introspection (memory / cost analysis)."""
 from __future__ import annotations
 
 
 def peak_memory_bytes(compiled) -> int:
-    """Best-available peak device memory for a compiled executable.
-
-    ``peak_memory_in_bytes`` covers execution-time allocations (temps and
-    outputs), NOT the resident argument buffers — call sites that want a
-    total footprint add ``argument_size_in_bytes - alias_size_in_bytes``
-    themselves, so the fallback must not fold arguments in or they would
-    be double-counted.
-    """
-    mem = compiled.memory_analysis()
-    peak = getattr(mem, "peak_memory_in_bytes", None)
-    if peak is not None:
-        return int(peak)
-    return int(mem.temp_size_in_bytes + mem.output_size_in_bytes)
+    """Peak device memory of a compiled executable's execution-time
+    allocations (temps and outputs), NOT the resident argument buffers —
+    call sites that want a total footprint add ``argument_size_in_bytes -
+    alias_size_in_bytes`` themselves."""
+    return int(compiled.memory_analysis().peak_memory_in_bytes)
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict on every version.
-
-    Old jaxlib returns ``[{...}]`` (one entry per computation); new
-    returns the dict directly. Multi-computation entries are summed for
-    the scalar keys the repo reads ("flops", "bytes accessed").
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, dict):
-        return cost
-    if not cost:
-        return {}
-    if len(cost) == 1:
-        return dict(cost[0])
-    out: dict = {}
-    for entry in cost:
-        for k, v in entry.items():
-            if isinstance(v, (int, float)):
-                out[k] = out.get(k, 0.0) + v
-            else:
-                out.setdefault(k, v)
-    return out
+    """``compiled.cost_analysis()`` ("flops", "bytes accessed", ...)."""
+    return dict(compiled.cost_analysis())

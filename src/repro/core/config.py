@@ -35,8 +35,7 @@ def shrink_block_rows(block_m: int, rows: int | None) -> int:
 
 
 # Tier names (dispatch-table keys) → config modes. "tpu"/"pallas"/"fused"
-# all mean the compiled-kernel path; dispatch degrades it to the
-# interpreter on non-TPU hosts.
+# all mean the compiled-kernel path, which only a TPU host can run.
 _TIER_ALIASES = {"tpu": "fused", "pallas": "fused", "fused": "fused",
                  "interpret": "interpret", "eager": "eager"}
 

@@ -22,6 +22,8 @@ import dataclasses
 import enum
 from typing import Callable
 
+import jax
+
 from repro.compat import probes
 from repro.core.config import DoRAConfig
 from repro.core.sharding import ComposeSharding
@@ -44,15 +46,15 @@ class KernelBackend:
 
 DISPATCH_TABLE: dict[str, KernelBackend] = {
     "tpu": KernelBackend("tpu", fused=True, interpret=False,
-                         available=probes.can_compile_pallas_tpu),
+                         available=probes.is_tpu),
     "interpret": KernelBackend("interpret", fused=True, interpret=True,
-                               available=probes.has_pallas),
+                               available=lambda: True),
     "eager": KernelBackend("eager", fused=False, interpret=False,
                            available=lambda: True),
 }
 
-# Config/env mode → table row. "fused" means "the compiled kernels" and
-# degrades to the interpreter off-TPU so one config runs on any host.
+# Config/env mode → table row. "fused" means "the compiled kernels": off a
+# TPU it raises instead of running the interpreter in their place.
 _MODE_TO_BACKEND = {"fused": "tpu", "interpret": "interpret",
                     "eager": "eager"}
 
@@ -89,9 +91,9 @@ def resolve_backend(cfg: DoRAConfig) -> KernelBackend:
     """Mode/override → the dispatch-table row to execute on.
 
     A *forced* tier (``REPRO_FORCE_TIER`` / ``cfg.force_tier``, surfaced
-    through ``cfg.resolve_mode()``) must be honored or fail loudly; the
-    only soft degrade is mode="fused" on a non-TPU host, which falls to the
-    interpreter so the same config validates on CPU (paper App. B).
+    through ``cfg.resolve_mode()``) is honored or fails loudly: a forced
+    ``tpu``/``fused`` tier off a TPU raises, naming the reason. CPU
+    validation of the kernels asks for ``interpret`` explicitly.
     """
     mode = cfg.resolve_mode()
     if mode == "auto":
@@ -101,11 +103,22 @@ def resolve_backend(cfg: DoRAConfig) -> KernelBackend:
     backend = DISPATCH_TABLE[name]
     if backend.available():
         return backend
-    if name == "tpu" and DISPATCH_TABLE["interpret"].available():
-        return DISPATCH_TABLE["interpret"]
     raise RuntimeError(
         f"kernel tier {name!r} was forced but is unavailable on this host: "
         f"{probes.why_unavailable(name)}")
+
+
+def unpartitionable(backend: KernelBackend) -> bool:
+    """True when ``backend`` compiles Mosaic kernels and the call site is
+    being traced under an (abstract) mesh of more than one device. XLA
+    cannot partition a Mosaic kernel, so there only a kernel that runs
+    shard-local under shard_map (the matmul-fused compose with its
+    sharding plan) may be used; every other call site takes the eager
+    tier, which GSPMD partitions. The step builders in
+    ``repro.launch.steps`` trace under their mesh. The interpreter lowers
+    to plain jnp and partitions like it."""
+    return (backend.fused and not backend.interpret
+            and jax.sharding.get_abstract_mesh().size > 1)
 
 
 def above_crossover(rows: int, d_out: int, cfg: DoRAConfig) -> bool:
@@ -183,6 +196,8 @@ def plan_compose(cfg: DoRAConfig, *, training: bool, rows: int,
         return KernelPlan(Tier.EAGER, "eager", False)
     tier = Tier.FUSED_BWD if training else Tier.FUSED_FWD
     mm = mm_fused_eligible(rank, cfg, rows_local)
+    if unpartitionable(backend) and not (mm and sharding is not None):
+        return KernelPlan(Tier.EAGER, "eager", False)
     return KernelPlan(tier, backend.name, backend.interpret,
                       matmul_fused=mm, sharding=sharding if mm else None)
 
@@ -195,8 +210,8 @@ def plan_norm(cfg: DoRAConfig, *, d_out: int) -> KernelPlan:
     if not shape_supported(d_out):
         return KernelPlan(Tier.EAGER, "eager", False)
     backend = resolve_backend(cfg)
-    if not backend.fused:
-        return KernelPlan(Tier.EAGER, backend.name, False)
+    if not backend.fused or unpartitionable(backend):
+        return KernelPlan(Tier.EAGER, "eager", False)
     return KernelPlan(Tier.FUSED_FWD, backend.name, backend.interpret)
 
 
@@ -213,8 +228,8 @@ def plan_gather(cfg: DoRAConfig | None, *, head_elems: int) -> KernelPlan:
     if cfg is None or not shape_supported(head_elems):
         return KernelPlan(Tier.EAGER, "eager", False)
     backend = resolve_backend(cfg)
-    if not backend.fused:
-        return KernelPlan(Tier.EAGER, backend.name, False)
+    if not backend.fused or unpartitionable(backend):
+        return KernelPlan(Tier.EAGER, "eager", False)
     return KernelPlan(Tier.FUSED_FWD, backend.name, backend.interpret)
 
 

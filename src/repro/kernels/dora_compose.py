@@ -53,7 +53,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.compat.pallas import pl, tpu_compiler_params
+from repro.compat.pallas import pl, pltpu
 from repro.core.config import shrink_block_rows
 
 _F32 = jnp.float32
@@ -121,6 +121,7 @@ def compose_fwd_pallas(base, lora, gm1, s: float, *,
             out_specs=(mat, mat),
             out_shape=(out_shape, out_shape),
             interpret=interpret,
+            metadata={"kernel": "compose_fwd_pallas"},
         )(base, lora, gm1)
     kern = functools.partial(_fwd_kernel, s=float(s))
     return pl.pallas_call(
@@ -130,6 +131,7 @@ def compose_fwd_pallas(base, lora, gm1, s: float, *,
         out_specs=mat,
         out_shape=out_shape,
         interpret=interpret,
+        metadata={"kernel": "compose_fwd_pallas"},
     )(base, lora, gm1)
 
 
@@ -147,6 +149,7 @@ def compose_bwd_pallas(dy, gm1, gs, *, block_m: int, block_n: int,
         out_specs=(mat, mat),
         out_shape=(out_shape, out_shape),
         interpret=interpret,
+        metadata={"kernel": "compose_bwd_pallas"},
     )(dy, gm1, gs)
 
 
@@ -233,6 +236,7 @@ def compose_mm_fwd_pallas(base, h, B, gm1, s: float, *,
         out_specs=mat,
         out_shape=jax.ShapeDtypeStruct((m, n), base.dtype),
         interpret=interpret,
+        metadata={"kernel": "compose_mm_fwd_pallas"},
     )(base, h, B, gm1)
 
 
@@ -264,7 +268,8 @@ def compose_mm_bwd_pallas(dy, B, gm1, gs, *, block_m: int, block_n: int,
         ),
         out_shape=(jax.ShapeDtypeStruct((m, n), dy.dtype),
                    jax.ShapeDtypeStruct((m, rp), _F32)),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        metadata={"kernel": "compose_mm_bwd_pallas"},
     )(dy, B, gm1, gs)

@@ -24,7 +24,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat.pallas import pl, tpu_compiler_params
+from repro.compat.pallas import pl, pltpu
 
 _F32 = jnp.float32
 
@@ -68,8 +68,9 @@ def norm_terms_pallas(W, A, B, *, block_rows: int, block_k: int,
             pl.BlockSpec((1, block_rows), lambda i, k: (0, i)),
         ),
         out_shape=(out_shape, out_shape),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        metadata={"kernel": "norm_terms_pallas"},
     )(W, A, B)
     return base_sq[0], cross[0]

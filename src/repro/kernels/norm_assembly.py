@@ -51,5 +51,6 @@ def assemble_norm_pallas(base_sq, cross, ba_sq, s: float, *,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((1, d_out), _F32),
         interpret=interpret,
+        metadata={"kernel": "assemble_norm_pallas"},
     )(*vecs)
     return out[0]

@@ -84,6 +84,7 @@ def _make_gather(n_blocks: int, bs: int, hd_flat: int, b: int, mb: int,
         out_shape=jax.ShapeDtypeStruct((b, mb, bs, hd_flat),
                                        jnp.dtype(dtype_name)),
         interpret=interpret,
+        metadata={"kernel": "paged_gather"},
     )
 
 
@@ -91,8 +92,6 @@ def paged_gather(pool, pages, *, interpret: bool | None = None):
     """Pallas tier of :func:`paged_gather_ref` (bitwise-identical: both
     tiers are copies + zero fill). Requires ``Hkv*hd % 128 == 0`` — the
     dispatch plan (:func:`repro.core.dispatch.plan_gather`) enforces it."""
-    if pl is None or pltpu is None:  # pragma: no cover - pallas-free host
-        return paged_gather_ref(pool, pages)
     interpret = resolve_interpret(interpret)
     n_blocks, bs, hkv, hd = pool.shape
     b, mb = pages.shape

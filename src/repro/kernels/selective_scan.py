@@ -28,8 +28,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.compat.pallas import (pl, resolve_interpret, tpu_compiler_params,
-                                 vmem)
+from repro.compat.pallas import pl, pltpu, resolve_interpret
 
 _F32 = jnp.float32
 
@@ -87,9 +86,10 @@ def selective_scan_pallas(dt, dtx, Bm, Cm, A_t, h0_t, *,
             jax.ShapeDtypeStruct((B, S, di), _F32),
             jax.ShapeDtypeStruct((B, n, di), _F32),
         ),
-        scratch_shapes=[vmem((n, block_di), _F32)],
-        compiler_params=tpu_compiler_params(
+        scratch_shapes=[pltpu.VMEM((n, block_di), _F32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        metadata={"kernel": "selective_scan_pallas"},
     )(dt, dtx, Bm, Cm, A_t, h0_t)
     return y, h_f

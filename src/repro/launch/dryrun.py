@@ -1,5 +1,9 @@
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
+The meshes are fake CPU devices; the roofline terms model the chip named
+by :data:`MODELED_DEVICE_KIND`, whose peaks come from
+``repro.roofline.PEAKS``.
+
 MUST be the first two lines (jax locks device count on first init):
 """
 import os
@@ -17,7 +21,10 @@ from repro.configs import ARCH_IDS, SHAPES, applicable_shapes, get_config
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import StepConfig, cell_specs
 from repro.obs import monotonic
-from repro.roofline import HW, analyze_hlo_text, model_flops, roofline_terms
+from repro.roofline import analyze_hlo_text, hw_for, model_flops, \
+    roofline_terms
+
+MODELED_DEVICE_KIND = "TPU v5 lite"
 
 
 def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
@@ -43,7 +50,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     cost = cxla.cost_analysis_dict(compiled)
     hlo = compiled.as_text()
     ana = analyze_hlo_text(hlo)
-    hw = HW()
+    hw = hw_for(MODELED_DEVICE_KIND)
     terms = roofline_terms(ana, hw)
 
     mcfg = cell["mcfg"]
@@ -58,6 +65,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     record = {
         "arch": arch, "shape": shape_name, "kind": spec.kind,
         "mesh": "2x16x16" if multi_pod else "16x16", "chips": chips,
+        "device_kind_modeled": MODELED_DEVICE_KIND,
         "compile_s": round(t_compile, 1),
         "memory": {
             "peak_bytes": peak_bytes,
@@ -92,7 +100,8 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
                 f.write(hlo)
     if verbose:
         gib = 1 << 30
-        print(f"[{record['mesh']}] {arch} x {shape_name}: compile "
+        print(f"[{record['mesh']}, modeling {MODELED_DEVICE_KIND}] "
+              f"{arch} x {shape_name}: compile "
               f"{t_compile:.0f}s | peak {record['memory']['peak_bytes']/gib:.2f}"
               f" GiB (args {record['memory']['argument_bytes']/gib:.2f}) | "
               f"compute {terms['compute_s']*1e3:.2f} ms, memory "

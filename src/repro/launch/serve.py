@@ -40,6 +40,7 @@ from repro.core import DoRAConfig
 from repro.core.adapter import stack_adapter_states
 from repro.core.adapter_cache import (AdapterHandle, AdapterStateCache,
                                       mesh_fingerprint)
+from repro.launch import compile_cache
 from repro.launch.steps import StepConfig, make_decode_step, \
     make_precompute_step, make_prefill_step
 from repro.launch.train import build_state
@@ -664,6 +665,7 @@ def main() -> None:
                          "exposition format (counters, gauges, and "
                          "tick/seconds latency histograms)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     mcfg = get_config(args.arch, smoke=args.smoke)
     dcfg = DoRAConfig(rank=args.rank, alpha=args.alpha, mode="auto")

@@ -62,6 +62,22 @@ def cross_entropy(logits, labels):
 # Steps.
 # ---------------------------------------------------------------------------
 
+def _under_mesh(mesh, step):
+    """``step`` traced under ``mesh``'s abstract mesh, so kernel dispatch
+    sees how many devices the program is partitioned over and keeps the
+    Pallas kernels XLA cannot partition out of it
+    (:func:`repro.core.dispatch.unpartitionable`)."""
+    if mesh is None:
+        return step
+
+    @functools.wraps(step)
+    def traced(*args):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return step(*args)
+
+    return traced
+
+
 def make_train_step(mcfg: ModelConfig, scfg: StepConfig, mesh=None, *,
                     batch: int, seq: int):
     """(params, adapters, opt_state, batch) -> (adapters', opt_state',
@@ -120,7 +136,7 @@ def make_train_step(mcfg: ModelConfig, scfg: StepConfig, mesh=None, *,
         metrics = {"loss": loss, **stats}
         return new_adapters, new_opt, metrics
 
-    return train_step
+    return _under_mesh(mesh, train_step)
 
 
 def make_prefill_step(mcfg: ModelConfig, scfg: StepConfig, mesh=None, *,
@@ -175,7 +191,7 @@ def make_prefill_step(mcfg: ModelConfig, scfg: StepConfig, mesh=None, *,
             new_cache["len"] = p_len.astype(new_cache["len"].dtype)
         return logits[:, -1], new_cache
 
-    return prefill_step
+    return _under_mesh(mesh, prefill_step)
 
 
 def make_prefill_into_slot_step(mcfg: ModelConfig, scfg: StepConfig,
@@ -313,7 +329,7 @@ def make_prefill_chunk_step(mcfg: ModelConfig, scfg: StepConfig,
                                "len": new_len,
                                "pages": cache["pages"]}
 
-    return prefill_chunk
+    return _under_mesh(mesh, prefill_chunk)
 
 
 def make_precompute_step(mcfg: ModelConfig, scfg: StepConfig, mesh=None, *,
@@ -354,7 +370,7 @@ def make_precompute_step(mcfg: ModelConfig, scfg: StepConfig, mesh=None, *,
             tree = constrain_tree(tree, serving_sh)
         return tree
 
-    return precompute_step
+    return _under_mesh(mesh, precompute_step)
 
 
 def make_decode_step(mcfg: ModelConfig, scfg: StepConfig, mesh=None, *,
@@ -398,7 +414,7 @@ def make_decode_step(mcfg: ModelConfig, scfg: StepConfig, mesh=None, *,
             training=False, tenant_groups=tg, **kw)
         return logits[:, -1], new_cache
 
-    return decode_step
+    return _under_mesh(mesh, decode_step)
 
 
 def make_draft_step(mcfg: ModelConfig, scfg: StepConfig, mesh=None, *,
@@ -418,7 +434,6 @@ def make_draft_step(mcfg: ModelConfig, scfg: StepConfig, mesh=None, *,
     verify step re-writes those exact positions with full-path K/V, so
     nothing base-flavored survives into the committed cache (see
     ``launch/engine.py``)."""
-    del mesh  # shardings are attached by the caller's jit, as for decode
 
     def draft_step(params, cache, batch_in):
         logits, new_cache, _ = forward(
@@ -426,7 +441,7 @@ def make_draft_step(mcfg: ModelConfig, scfg: StepConfig, mesh=None, *,
             tokens=batch_in["tokens"])
         return logits[:, -1], new_cache
 
-    return draft_step
+    return _under_mesh(mesh, draft_step)
 
 
 def make_verify_step(mcfg: ModelConfig, scfg: StepConfig, mesh=None, *,
@@ -454,7 +469,6 @@ def make_verify_step(mcfg: ModelConfig, scfg: StepConfig, mesh=None, *,
     ``dynamic_groups``: as for :func:`make_decode_step` — per-row
     adapters from the traced ``batch_in["adapter_idx"]``, one verify
     executable per window across every tenant mix."""
-    del mesh
     if dynamic_groups and tenant_groups is not None:
         raise ValueError(
             "dynamic_groups=True takes the per-row adapter index from "
@@ -470,7 +484,7 @@ def make_verify_step(mcfg: ModelConfig, scfg: StepConfig, mesh=None, *,
             tokens=batch_in["tokens"])
         return logits, new_cache
 
-    return verify_step
+    return _under_mesh(mesh, verify_step)
 
 
 # ---------------------------------------------------------------------------

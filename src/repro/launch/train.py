@@ -28,17 +28,31 @@ from repro.checkpoint import (CheckpointConfig, Heartbeat,
 from repro.configs import get_config
 from repro.core import DoRAConfig
 from repro.data import DataConfig, make_train_iterator, prefetch
+from repro.launch import compile_cache
+from repro.launch import sharding as S
 from repro.launch.steps import StepConfig, make_train_step
-from repro.models import init_adapters, init_params
+from repro.models import adapter_shapes, init_adapters, init_params
 from repro.obs import monotonic
 from repro.optim import OptimizerConfig, adamw_init
 
 
-def build_state(mcfg, dcfg, seed: int = 0):
+def build_state(mcfg, dcfg, seed: int = 0, mesh=None):
+    """(params, adapters, opt_state) from ``seed``, each initialised by one
+    jitted program straight into its layout: the ``mesh`` shardings of
+    :mod:`repro.launch.sharding` when a mesh is given (no array is ever
+    whole on one device first), the default device otherwise."""
+    p_sh = a_sh = o_sh = None
+    if mesh is not None:
+        p_sh = S.param_sharding(mcfg, mesh)
+        a_sh = S.adapter_sharding(mcfg, dcfg, mesh)
+        o_sh = S.opt_state_sharding(a_sh, mesh, adapter_shapes(mcfg, dcfg))
     key = jax.random.PRNGKey(seed)
-    params = init_params(key, mcfg)
-    adapters = init_adapters(jax.random.fold_in(key, 1), mcfg, params, dcfg)
-    opt_state = adamw_init(adapters)
+    params = jax.jit(init_params, static_argnums=1,
+                     out_shardings=p_sh)(key, mcfg)
+    adapters = jax.jit(init_adapters, static_argnums=(1, 3),
+                       out_shardings=a_sh)(
+        jax.random.fold_in(key, 1), mcfg, params, dcfg)
+    opt_state = jax.jit(adamw_init, out_shardings=o_sh)(adapters)
     return params, adapters, opt_state
 
 
@@ -144,7 +158,9 @@ def main() -> None:
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--heartbeat-dir", default="")
     ap.add_argument("--log-every", type=int, default=10)
-    train(ap.parse_args())
+    args = ap.parse_args()
+    compile_cache.enable()
+    train(args)
 
 
 if __name__ == "__main__":

@@ -27,8 +27,9 @@ Terms (seconds, per device — the HLO is already per-device):
     memory     = hbm_bytes / hbm_bw
     collective = link_bytes / link_bw
 
-Hardware constants are TPU v5e-class: 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI (assignment-given).
+Hardware constants come from :data:`PEAKS`, keyed by the device's
+``device_kind``; a kind missing from the table is an error, never a
+default (:func:`hw_for`).
 """
 from __future__ import annotations
 
@@ -51,11 +52,32 @@ _COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
 
 @dataclasses.dataclass(frozen=True)
 class HW:
-    """Per-chip hardware constants (TPU v5e-class, assignment-given)."""
-    peak_flops: float = 197e12        # bf16
-    hbm_bw: float = 819e9             # bytes/s
-    link_bw: float = 50e9             # bytes/s per ICI link
-    hbm_bytes: float = 16 * 2**30     # capacity, for the fits-check
+    """Per-chip hardware constants."""
+    peak_flops: float                 # bf16 FLOP/s
+    hbm_bw: float                     # bytes/s
+    link_bw: float                    # bytes/s per ICI link
+    hbm_bytes: float                  # capacity, for the fits-check
+
+
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``. TPU v5e
+# ("TPU v5 lite"): Google Cloud documentation, "TPU v5e" — 197 TFLOP/s
+# bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of ICI over 4 links
+# (= 50 GB/s per link).
+PEAKS: dict[str, HW] = {
+    "TPU v5 lite": HW(peak_flops=197e12, hbm_bw=819e9, link_bw=50e9,
+                      hbm_bytes=16 * 2**30),
+}
+
+
+def hw_for(device_kind: str) -> HW:
+    """The peaks of ``device_kind``; an unknown kind raises."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peaks for device kind {device_kind!r} (known: "
+            f"{sorted(PEAKS)}); add its published numbers to PEAKS") \
+            from None
 
 
 def _shape_bytes_and_dims(type_str: str):
@@ -381,7 +403,7 @@ def analyze_hlo_text(hlo: str) -> HloAnalysis:
     return out
 
 
-def roofline_terms(analysis: HloAnalysis, hw: HW = HW()) -> dict[str, float]:
+def roofline_terms(analysis: HloAnalysis, hw: HW) -> dict[str, float]:
     compute = analysis.flops / hw.peak_flops
     memory = analysis.hbm_bytes / hw.hbm_bw
     collective = analysis.link_bytes / hw.link_bw

@@ -1,7 +1,7 @@
 """The compat layer itself: tree-path round-trips, compiler-params
-construction under both Pallas API names (monkeypatched), and forced-tier
-dispatch selection. These tests guard the guarantee every other module
-relies on: one JAX upgrade == one shim change, zero call-site changes.
+construction, probes, and forced-tier dispatch selection. These tests
+guard the guarantee every other module relies on: each drifting JAX API
+is named in one place.
 """
 from __future__ import annotations
 
@@ -60,52 +60,13 @@ def test_flatten_with_path_honors_is_leaf():
 
 
 # ---------------------------------------------------------------------------
-# pallas compiler params under both API names
+# pallas compiler params
 # ---------------------------------------------------------------------------
 
-class _NewStyleParams:
-    def __init__(self, dimension_semantics=None):
-        self.dimension_semantics = dimension_semantics
-
-
-class _OldStyleParams(_NewStyleParams):
-    pass
-
-
-def test_compiler_params_prefers_new_name(monkeypatch):
-    monkeypatch.setattr(cpal.pltpu, "CompilerParams", _NewStyleParams,
-                        raising=False)
-    out = cpal.tpu_compiler_params(
-        dimension_semantics=("parallel", "arbitrary"))
-    assert isinstance(out, _NewStyleParams)
-    assert out.dimension_semantics == ("parallel", "arbitrary")
-
-
-def test_compiler_params_falls_back_to_old_name(monkeypatch):
-    # Simulate an old JAX: no CompilerParams, only TPUCompilerParams.
-    monkeypatch.delattr(cpal.pltpu, "CompilerParams", raising=False)
-    monkeypatch.setattr(cpal.pltpu, "TPUCompilerParams", _OldStyleParams,
-                        raising=False)
-    out = cpal.tpu_compiler_params(dimension_semantics=("parallel",))
-    assert isinstance(out, _OldStyleParams)
-    assert out.dimension_semantics == ("parallel",)
-
-
-def test_compiler_params_drops_unknown_tuning_kwargs(monkeypatch):
-    monkeypatch.delattr(cpal.pltpu, "CompilerParams", raising=False)
-    monkeypatch.setattr(cpal.pltpu, "TPUCompilerParams", _OldStyleParams,
-                        raising=False)
-    out = cpal.tpu_compiler_params(dimension_semantics=("arbitrary",),
-                                   vmem_limit_bytes=1 << 20)
-    assert isinstance(out, _OldStyleParams)
-    assert out.dimension_semantics == ("arbitrary",)
-
-
 def test_compiler_params_constructs_on_installed_jax():
-    """Whatever the installed JAX calls the class, construction works and
-    pallas_call accepts the result (interpret mode, CPU)."""
-    params = cpal.tpu_compiler_params(
-        dimension_semantics=("parallel",))
+    """The kernels' compiler params construct on the installed JAX and
+    pallas_call accepts them (interpret mode, CPU)."""
+    params = cpal.pltpu.CompilerParams(dimension_semantics=("parallel",))
 
     def kern(x_ref, o_ref):
         o_ref[...] = x_ref[...] * 2.0
@@ -126,8 +87,7 @@ def test_compiler_params_constructs_on_installed_jax():
 def test_resolve_interpret_follows_backend():
     assert cpal.resolve_interpret(True) is True
     assert cpal.resolve_interpret(False) is False
-    assert cpal.resolve_interpret(None) == (
-        not probes.can_compile_pallas_tpu())
+    assert cpal.resolve_interpret(None) == (not probes.is_tpu())
 
 
 # ---------------------------------------------------------------------------
@@ -164,10 +124,8 @@ def test_peak_memory_and_cost_dict_on_installed_jax():
 
 def test_probes_consistent():
     assert probes.backend_platform() in ("cpu", "gpu", "tpu")
-    assert probes.has_pallas()       # this repo requires pallas
-    assert probes.has_pallas_tpu()
     if probes.backend_platform() != "tpu":
-        assert not probes.can_compile_pallas_tpu()
+        assert not probes.is_tpu()
         assert "tpu" not in dispatch.available_backends()
     assert "eager" in dispatch.available_backends()
     assert "interpret" in dispatch.available_backends()
@@ -210,13 +168,15 @@ def test_force_tier_config_field(monkeypatch):
     assert plan.interpret is True
 
 
-def test_force_tier_tpu_degrades_to_interpret_off_tpu(monkeypatch):
+def test_force_tier_tpu_raises_off_tpu(monkeypatch):
+    """A forced compiled-kernel tier never runs the interpreter in its
+    place: off a TPU it raises, naming why the tier is unavailable."""
     monkeypatch.delenv("REPRO_FORCE_TIER", raising=False)
-    if probes.is_tpu():
-        pytest.skip("degrade path only exists off-TPU")
-    plan = _plan(DoRAConfig(force_tier="tpu"))
-    assert plan.tier is dispatch.Tier.FUSED_BWD
-    assert plan.backend == "interpret"
+    monkeypatch.setattr(probes, "backend_platform", lambda: "cpu")
+    with pytest.raises(RuntimeError, match="backend is 'cpu', not 'tpu'"):
+        _plan(DoRAConfig(force_tier="tpu"))
+    with pytest.raises(RuntimeError, match="forced but is unavailable"):
+        _plan(DoRAConfig(mode="fused"))
 
 
 def test_force_tier_rejects_unknown_env(monkeypatch):
@@ -290,3 +250,32 @@ def test_forced_interpret_matches_eager_end_to_end(monkeypatch, rng_key):
     y_eager = dora_linear(x, W, adapter, cfg, training=True)
     np.testing.assert_allclose(np.asarray(y_interp), np.asarray(y_eager),
                                rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# compiled kernels under a multi-device mesh
+# ---------------------------------------------------------------------------
+
+def test_compiled_kernels_leave_partitioned_programs(monkeypatch):
+    """XLA cannot partition a Mosaic kernel: traced under a mesh of more
+    than one device, the compiled tier routes the norm, the gather and an
+    un-sharded compose to eager. One device, or the interpreter (plain
+    jnp), keeps the kernels."""
+    monkeypatch.delenv("REPRO_FORCE_TIER", raising=False)
+    monkeypatch.setattr(probes, "backend_platform", lambda: "tpu")
+    cfg = DoRAConfig(mode="fused")
+
+    def plans(c):
+        return (dispatch.plan_norm(c, d_out=256),
+                dispatch.plan_gather(c, head_elems=512),
+                _plan(c, rows=4096, training=False))
+
+    assert all(p.fused and p.backend == "tpu" for p in plans(cfg))
+    four = jax.sharding.AbstractMesh((4,), ("model",))
+    with jax.sharding.use_abstract_mesh(four):
+        assert all(p.tier is dispatch.Tier.EAGER for p in plans(cfg))
+        interp = plans(DoRAConfig(mode="interpret"))
+        assert all(p.fused and p.interpret for p in interp)
+    one = jax.sharding.AbstractMesh((1,), ("model",))
+    with jax.sharding.use_abstract_mesh(one):
+        assert all(p.fused and p.backend == "tpu" for p in plans(cfg))

@@ -91,14 +91,14 @@ class TestDispatch:
             is dp.Tier.FUSED_FWD
 
     def test_bad_shape_routes_eager(self):
-        cfg = DoRAConfig(mode="fused")
+        cfg = DoRAConfig(mode="interpret")
         assert dp.select_tier(cfg, training=True, rows=10**6, d_out=100) \
             is dp.Tier.EAGER
 
     def test_env_force_off(self):
         os.environ["REPRO_DORA_FUSED"] = "0"
         try:
-            cfg = DoRAConfig(mode="fused")
+            cfg = DoRAConfig(mode="interpret")
             assert dp.select_tier(cfg, training=True, rows=10**6,
                                   d_out=8192) is dp.Tier.EAGER
         finally:

@@ -187,9 +187,13 @@ def test_dora_linear_mm_grads_match_eager_tier():
     ge = jax.grad(loss)(adapter, cfg_e)
     gf = jax.grad(loss)(adapter, cfg_f)
     for name in ("A", "B", "m"):
+        # The tiers reduce in different fp32 orders, so an entry's error
+        # scales with the gradient's largest entry (~1e3 here), not with
+        # the entry: atol is 1e-6 of that scale (~8 fp32 ulps).
+        scale = float(np.max(np.abs(np.asarray(ge[name]))))
         np.testing.assert_allclose(
             np.asarray(ge[name]), np.asarray(gf[name]),
-            rtol=1e-4, atol=1e-4, err_msg=name)
+            rtol=1e-4, atol=max(1e-4, 1e-6 * scale), err_msg=name)
 
 
 class TestDispatchFlag:

@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.roofline import HW, analyze_hlo_text, model_flops, \
+from repro.roofline import HW, analyze_hlo_text, hw_for, model_flops, \
     roofline_terms
 from repro.roofline.analysis import _shape_bytes_and_dims
 
@@ -79,9 +79,18 @@ def test_collective_traffic_factors():
 def test_roofline_terms_dominance():
     ana = analyze_hlo_text(HLO_DOT)
     terms = roofline_terms(ana, HW(peak_flops=1.0, hbm_bw=1e30,
-                                   link_bw=1e30))
+                                   link_bw=1e30, hbm_bytes=1.0))
     assert terms["dominant"] == "compute"
     assert terms["roofline_fraction"] == 1.0
+
+
+def test_peaks_keyed_by_device_kind():
+    """The peaks table is keyed by jax's device_kind; a kind it does not
+    hold is an error, never a silent v5e default."""
+    v5e = hw_for("TPU v5 lite")
+    assert v5e.peak_flops == 197e12 and v5e.hbm_bw == 819e9
+    with pytest.raises(ValueError, match="no peaks for device kind 'cpu'"):
+        hw_for("cpu")
 
 
 def test_model_flops_train_vs_serve():
