@@ -1,0 +1,7 @@
+"""idle_share.train: share of the traced window in which no operation ran
+on the device (1 - union of the device's op intervals / window).
+Moves train_tokens_per_s."""
+
+
+def read(ctx):
+    return 100.0 * ctx["trace"].idle_share()
