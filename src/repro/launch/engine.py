@@ -90,7 +90,7 @@ from repro.launch.steps import (StepConfig, make_decode_step,
                                 make_verify_step)
 from repro.models import init_cache
 from repro.models.config import ModelConfig
-from repro.obs.trace import TraceRecorder
+from repro.obs.trace import TraceRecorder, span
 
 #: Every finish_reason a RequestResult can carry.
 #:   eos           the request's eos_id was sampled
@@ -814,13 +814,14 @@ class DecodeEngine:
         freeing are visible exactly when they must be."""
         if not self._pages_dirty:
             return
-        arr = jnp.asarray(np.array(self._pages_np))
-        if self._cache_out_sh is not None:
-            arr = jax.device_put(arr, self._cache_out_sh["pages"])
-        cache = dict(self.cache)
-        cache["pages"] = arr
-        self.cache = cache
-        self._pages_dirty = False
+        with span("engine.pages"):
+            arr = jnp.asarray(np.array(self._pages_np))
+            if self._cache_out_sh is not None:
+                arr = jax.device_put(arr, self._cache_out_sh["pages"])
+            cache = dict(self.cache)
+            cache["pages"] = arr
+            self.cache = cache
+            self._pages_dirty = False
 
     def _block_victim(self) -> int | None:
         """Deterministic reclaim order under pool exhaustion: lowest
@@ -925,6 +926,28 @@ class DecodeEngine:
         if slot.budget <= 0:
             return slot.finish_cap
         return None
+
+    def _first_token(self, slot: _Slot, logits, on_token) -> None:
+        """Complete an admission: fetch the prefill's last-position
+        ``logits`` ([1, V]), sample and deliver the first token — or, when
+        the row is non-finite, quarantine it before it ever decodes."""
+        req = slot.req
+        with span("engine.fetch"):
+            row = np.asarray(logits)[0]
+        with span("engine.sample", rows=1):
+            if self._nan_targets([slot.idx]):
+                row = np.full_like(row, np.nan)
+                self._injected_nans += 1
+            if not np.isfinite(row).all():
+                self._emit("quarantined", rid=req.request_id, slot=slot.idx,
+                           at="admission")
+                self._finish(slot, "error_numeric")
+                return
+            tok = self._sample_rows([row], [(req.key_id, slot.n_prior)])[0]
+        with span("engine.deliver"):
+            reason = self._note_token(slot, tok, on_token)
+            if reason is not None:
+                self._finish(slot, reason)   # slot free again
 
     def _error_result(self, req: EngineRequest, e: Exception) -> None:
         res = RequestResult(
@@ -1170,7 +1193,7 @@ class DecodeEngine:
             slot.handle = req.adapter
             slot.state = state
             if self._dynamic:
-                self._dyn_assign(idx, req.adapter, state)
+                self._dyn_assign(idx, req.adapter, state, req.request_id)
             slot.admitted_step = self._steps
             slot.pos = 0
             slot.n_prior = (0 if req.prefix is None
@@ -1189,7 +1212,7 @@ class DecodeEngine:
             # Claim the fleet-stack position BEFORE the prefill: a
             # budget-1 request that retires inside this admission still
             # releases a position it actually held.
-            self._dyn_assign(idx, req.adapter, state)
+            self._dyn_assign(idx, req.adapter, state, req.request_id)
         toks = np.zeros((1, self.max_len), np.int32)
         toks[0, :P] = req.prompt
         logits, self.cache = self._prefill(
@@ -1220,21 +1243,7 @@ class DecodeEngine:
         slot.finish_cap = (req.resume_cap if req.resume_cap is not None
                            else ("length" if req.max_new_tokens <= room
                                  else "max_len"))
-        row = np.asarray(logits)[0]
-        if self._nan_targets([idx]):
-            row = np.full_like(row, np.nan)
-            self._injected_nans += 1
-        if not np.isfinite(row).all():
-            # Quarantine at admission: the prefill produced non-finite
-            # logits for THIS row — retire it before it ever decodes.
-            self._emit("quarantined", rid=req.request_id, slot=idx,
-                       at="admission")
-            self._finish(slot, "error_numeric")
-            return True
-        tok = self._sample_rows([row], [(req.key_id, slot.n_prior)])[0]
-        reason = self._note_token(slot, tok, on_token)
-        if reason is not None:
-            self._finish(slot, reason)   # slot free again
+        self._first_token(slot, logits, on_token)
         return True
 
     def _admit(self, on_token=None) -> None:
@@ -1293,13 +1302,13 @@ class DecodeEngine:
             self._dyn_insert = jax.jit(insert, donate_argnums=(0,))
         return self._dyn_insert
 
-    def _dyn_assign(self, idx: int, handle, state) -> None:
-        """Give slot ``idx`` a fleet-stack position for ``handle``: slots
-        sharing a handle share its position (refcounted), a NEW handle
-        claims a free position and writes its serving tree there (the one
-        churn-time device copy — decode ticks never restack). The stack
-        is built lazily from the first state's leaf shapes (zeros rows:
-        finite garbage nothing indexes)."""
+    def _dyn_assign(self, idx: int, handle, state, request_id: int) -> None:
+        """Give slot ``idx`` (seating ``request_id``) a fleet-stack
+        position for ``handle``: slots sharing a handle share its position
+        (refcounted), a NEW handle claims a free position and writes its
+        serving tree there (the one churn-time device copy — decode ticks
+        never restack). The stack is built lazily from the first state's
+        leaf shapes (zeros rows: finite garbage nothing indexes)."""
         ent = self._dyn_pos.get(handle)
         if ent is not None:
             ent[1] += 1
@@ -1311,8 +1320,10 @@ class DecodeEngine:
                     lambda l: jnp.zeros(
                         (l.shape[0], self.slots) + l.shape[1:], l.dtype),
                     state)
-            self._dyn_stack = self._dyn_insert_fn()(
-                self._dyn_stack, state, jnp.asarray(pos, jnp.int32))
+            with span("engine.stack_insert", request_id=request_id,
+                      slot=idx):
+                self._dyn_stack = self._dyn_insert_fn()(
+                    self._dyn_stack, state, jnp.asarray(pos, jnp.int32))
             self._stack_inserts += 1
         self._dyn_idx_np[idx] = ent[0]
         self._dyn_idx_cached = None
@@ -1548,12 +1559,14 @@ class DecodeEngine:
             self._emit("chunk_prefill", rid=req.request_id, slot=idx,
                        start=start, chunk_len=c_len, final=final)
             self._flush_pages()
-            logits, self.cache = self._chunk_prefill(
-                self.params, slot.state, self.cache,
-                {"tokens": jnp.asarray(toks),
-                 "slot": jnp.asarray(idx, jnp.int32),
-                 "start": jnp.asarray(start, jnp.int32),
-                 "chunk_len": jnp.asarray(c_len, jnp.int32)})
+            with span("engine.chunk", request_id=req.request_id, slot=idx,
+                      start=start, tokens=c_len):
+                logits, self.cache = self._chunk_prefill(
+                    self.params, slot.state, self.cache,
+                    {"tokens": jnp.asarray(toks),
+                     "slot": jnp.asarray(idx, jnp.int32),
+                     "start": jnp.asarray(start, jnp.int32),
+                     "chunk_len": jnp.asarray(c_len, jnp.int32)})
             if not final:
                 slot.chunk_next = start + C
                 continue
@@ -1569,19 +1582,7 @@ class DecodeEngine:
             slot.finish_cap = (req.resume_cap if req.resume_cap is not None
                                else ("length" if req.max_new_tokens <= room
                                      else "max_len"))
-            row = np.asarray(logits)[0]
-            if self._nan_targets([idx]):
-                row = np.full_like(row, np.nan)
-                self._injected_nans += 1
-            if not np.isfinite(row).all():
-                self._emit("quarantined", rid=req.request_id, slot=idx,
-                           at="admission")
-                self._finish(slot, "error_numeric")
-                continue
-            tok = self._sample_rows([row], [(req.key_id, slot.n_prior)])[0]
-            reason = self._note_token(slot, tok, on_token)
-            if reason is not None:
-                self._finish(slot, reason)
+            self._first_token(slot, logits, on_token)
 
     def _decode_tick(self, active: list[int], on_token) -> None:
         """One plain batched decode over the active slots."""
@@ -1590,31 +1591,35 @@ class DecodeEngine:
             if not active:
                 return
             self._flush_pages()
-        toks = np.zeros((self.slots, 1), np.int32)
-        for i in active:
-            toks[i, 0] = self._slots[i].last_token
         groups, adapters = self._slot_grouping()
         decode = self._get_decode(groups)
-        batch_in = {"tokens": jnp.asarray(toks)}
-        if groups == "dynamic":
-            batch_in["adapter_idx"] = self._dyn_idx()
-        logits, self.cache = decode(self.params, adapters, self.cache,
-                                    batch_in)
-        logits_np = np.asarray(logits)      # the sampling sync
+        with span("engine.decode", rows=len(active)):
+            toks = np.zeros((self.slots, 1), np.int32)
+            for i in active:
+                toks[i, 0] = self._slots[i].last_token
+            batch_in = {"tokens": jnp.asarray(toks)}
+            if groups == "dynamic":
+                batch_in["adapter_idx"] = self._dyn_idx()
+            logits, self.cache = decode(self.params, adapters, self.cache,
+                                        batch_in)
+        with span("engine.fetch"):
+            logits_np = np.asarray(logits)      # the sampling sync
         self._decode_steps += 1
         self._slot_steps += len(active)
-        active, logits_np = self._quarantine(active, logits_np)
-        toks_out = self._sample_rows(
-            [logits_np[i] for i in active],
-            [(self._slots[i].req.key_id,
-              self._slots[i].n_prior + len(self._slots[i].generated))
-             for i in active])
-        for i, tok in zip(active, toks_out):
-            slot = self._slots[i]
-            slot.pos += 1               # this decode wrote K/V at pos
-            reason = self._note_token(slot, tok, on_token)
-            if reason is not None:
-                self._finish(slot, reason)
+        with span("engine.sample", rows=len(active)):
+            active, logits_np = self._quarantine(active, logits_np)
+            toks_out = self._sample_rows(
+                [logits_np[i] for i in active],
+                [(self._slots[i].req.key_id,
+                  self._slots[i].n_prior + len(self._slots[i].generated))
+                 for i in active])
+        with span("engine.deliver"):
+            for i, tok in zip(active, toks_out):
+                slot = self._slots[i]
+                slot.pos += 1               # this decode wrote K/V at pos
+                reason = self._note_token(slot, tok, on_token)
+                if reason is not None:
+                    self._finish(slot, reason)
 
     def _speculative_tick(self, active: list[int], on_token) -> None:
         """Draft k base-only tokens per row, verify the k+1 window in one
@@ -1643,14 +1648,17 @@ class DecodeEngine:
         draft = self._get_draft()
         drafts = np.zeros((self.slots, k), np.int32)
         for j in range(k):
-            logits, self.cache = draft(self.params, self.cache,
-                                       {"tokens": jnp.asarray(cur)})
-            lnp = np.asarray(logits)
+            with span("engine.decode", rows=len(active)):
+                logits, self.cache = draft(self.params, self.cache,
+                                           {"tokens": jnp.asarray(cur)})
+            with span("engine.fetch"):
+                lnp = np.asarray(logits)
             self._draft_steps += 1
-            for i in active:
-                t = int(np.argmax(lnp[i]))
-                drafts[i, j] = t
-                cur[i, 0] = t
+            with span("engine.sample", rows=len(active)):
+                for i in active:
+                    t = int(np.argmax(lnp[i]))
+                    drafts[i, j] = t
+                    cur[i, 0] = t
 
         # -- verify: ONE grouped full-DoRA forward over [t0, q1..qk] --------
         self._sync_len(base_len)    # rewind over the drafts' len advance
@@ -1660,43 +1668,47 @@ class DecodeEngine:
             win[i, 1:] = drafts[i]
         groups, adapters = self._slot_grouping()
         verify = self._get_verify(groups, k + 1)
-        batch_in = {"tokens": jnp.asarray(win)}
-        if groups == "dynamic":
-            batch_in["adapter_idx"] = self._dyn_idx()
-        logits, self.cache = verify(self.params, adapters, self.cache,
-                                    batch_in)
-        logits_np = np.asarray(logits)       # [slots, k+1, V]
+        with span("engine.decode", rows=len(active)):
+            batch_in = {"tokens": jnp.asarray(win)}
+            if groups == "dynamic":
+                batch_in["adapter_idx"] = self._dyn_idx()
+            logits, self.cache = verify(self.params, adapters, self.cache,
+                                        batch_in)
+        with span("engine.fetch"):
+            logits_np = np.asarray(logits)       # [slots, k+1, V]
         self._verify_steps += 1
-        # Quarantine BEFORE acceptance: a poisoned row emits nothing (its
-        # verify window is garbage end to end) and its rewind target is 0
-        # — the freed row's buffer is garbage either way.
-        active, logits_np = self._quarantine(active, logits_np)
+        with span("engine.sample", rows=len(active)):
+            # Quarantine BEFORE acceptance: a poisoned row emits nothing
+            # (its verify window is garbage end to end) and its rewind
+            # target is 0 — the freed row's buffer is garbage either way.
+            active, logits_np = self._quarantine(active, logits_np)
+            # true[i][j] = the token plain decode would emit after window
+            # position j (valid as long as window[:j+1] matches the true
+            # stream — which holds exactly up to the first draft miss).
+            true = {i: np.argmax(logits_np[i], axis=-1) for i in active}
 
         # -- accept: longest matching prefix per row, then rewind -----------
         accepted_this = 0
         new_len = np.zeros((self.slots,), np.int32)
-        for i in active:
-            slot = self._slots[i]
-            # true[j] = the token plain decode would emit after window
-            # position j (valid as long as window[:j+1] matches the true
-            # stream — which holds exactly up to the first draft miss).
-            true = np.argmax(logits_np[i], axis=-1)
-            a = 0
-            while a < k and drafts[i, a] == true[a]:
-                a += 1
-            self._accepted_drafts += a
-            accepted_this += a
-            # emit true[0..a]: the a accepted drafts plus the verify's
-            # own next token (a rejected draft's correction, or the
-            # bonus token after a fully-accepted window).
-            for tok in true[:a + 1]:
-                slot.pos += 1
-                reason = self._note_token(slot, int(tok), on_token)
-                if reason is not None:
-                    self._finish(slot, reason)
-                    break
-            if slot.active:
-                new_len[i] = slot.pos
+        with span("engine.deliver"):
+            for i in active:
+                slot = self._slots[i]
+                a = 0
+                while a < k and drafts[i, a] == true[i][a]:
+                    a += 1
+                self._accepted_drafts += a
+                accepted_this += a
+                # emit true[0..a]: the a accepted drafts plus the verify's
+                # own next token (a rejected draft's correction, or the
+                # bonus token after a fully-accepted window).
+                for tok in true[i][:a + 1]:
+                    slot.pos += 1
+                    reason = self._note_token(slot, int(tok), on_token)
+                    if reason is not None:
+                        self._finish(slot, reason)
+                        break
+                if slot.active:
+                    new_len[i] = slot.pos
         if self._paged:
             # Speculative rewind frees the dead tail: blocks past each
             # surviving row's accepted frontier (allocated for the k+1
@@ -1729,22 +1741,25 @@ class DecodeEngine:
         active slot. Returns the requests that FINISHED during this tick
         (also retrievable via :meth:`results`).
         ``on_token(request_id, token)`` streams every sampled token."""
-        before = set(self._results)
-        self._apply_tick_faults()
-        self._expire_deadlines()
-        self._admit(on_token)
-        if self._paged:
-            self._chunk_tick(on_token)
-        active = [i for i, s in enumerate(self._slots) if s.active]
-        if active:
-            if self._speculative_ok(active):
-                self._speculative_tick(active, on_token)
-            else:
-                self._decode_tick(active, on_token)
-        self._nan_tick = ()
-        self._steps += 1
-        return [self._results[rid]
-                for rid in sorted(set(self._results) - before)]
+        with span("engine.tick", tick=self._steps):
+            before = set(self._results)
+            self._apply_tick_faults()
+            self._expire_deadlines()
+            if self._queue:
+                with span("engine.admit", queued=len(self._queue)):
+                    self._admit(on_token)
+            if self._paged:
+                self._chunk_tick(on_token)
+            active = [i for i, s in enumerate(self._slots) if s.active]
+            if active:
+                if self._speculative_ok(active):
+                    self._speculative_tick(active, on_token)
+                else:
+                    self._decode_tick(active, on_token)
+            self._nan_tick = ()
+            self._steps += 1
+            return [self._results[rid]
+                    for rid in sorted(set(self._results) - before)]
 
     def run(self, on_token=None) -> list[RequestResult]:
         """Drive :meth:`step` until the queue and slot table drain, then

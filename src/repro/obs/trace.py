@@ -26,6 +26,10 @@ Exports: :meth:`TraceRecorder.to_jsonl` (one event per line, stable key
 order) and :meth:`TraceRecorder.to_chrome_trace` (Chrome ``trace_event``
 JSON — slots as tracks, requests as spans, token/fault instants —
 loadable in Perfetto or ``chrome://tracing``).
+
+:func:`span` is the other half: a profiler span around one stage of an
+engine tick, on the clock of the device trace the JAX profiler records,
+so the host's share of a tick can be laid over the device's ops.
 """
 from __future__ import annotations
 
@@ -34,6 +38,8 @@ import json
 import time
 from collections import deque
 from typing import Any, Iterator
+
+import jax
 
 #: Monotonic wall-clock for latency deltas. ``time.perf_counter`` is
 #: guaranteed monotone (``time.time`` is not: NTP steps can send it
@@ -54,6 +60,15 @@ LIFECYCLE_EVENTS = ("submitted", "queued", "admitted", "chunk_prefill",
 AUX_EVENTS = ("fault", "quarantined", "spec_disabled", "spec_reenabled",
               "busy_rejected", "spill", "reload")
 EVENT_NAMES = LIFECYCLE_EVENTS + AUX_EVENTS
+
+
+def span(name: str, **args: Any) -> jax.profiler.TraceAnnotation:
+    """A span named ``name`` on the profiler's host plane, on the same
+    clock as the device's ops. ``name`` is a fixed string; ids and counts
+    go in ``args`` (host ints, kept as the event's stats), never into the
+    name. With no profiler running it costs only the profiler's activity
+    check, and it never touches the device (docs/observability.md)."""
+    return jax.profiler.TraceAnnotation(name, **args)
 
 
 @dataclasses.dataclass(frozen=True)

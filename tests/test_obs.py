@@ -12,11 +12,18 @@ Plus the plumbing underneath: ring bounding/overflow accounting,
 histogram bucket edges, exporter round-trips (JSONL, Chrome
 trace_event, Prometheus text, JSON), derived lifecycle latencies, and
 the adapter-cache spill/reload event hook.
+
+The engine's profiler spans (``repro.obs.span``) are held to the same
+contract under ``jax.profiler``, and read back from the profile's host
+plane: one ``engine.tick`` per tick, one dispatch/fetch/sample triple per
+decode executable run, and ``request_id`` args that join the recorder's.
 """
 from __future__ import annotations
 
+import glob
 import json
 import math
+import os
 
 import jax
 import jax.numpy as jnp
@@ -323,12 +330,12 @@ def _setup():
 
 
 def _drive(trace=None, *, plan=None, deadline=None, speculative_k=0,
-           paged=False):
+           paged=False, dynamic_grouping=False):
     mcfg, scfg, params, cache, prompts = _setup()
     eng = DecodeEngine(mcfg, scfg, params, slots=2, max_len=ML,
                        adapter_cache=cache, fault_plan=plan,
                        speculative_k=speculative_k, paged=paged,
-                       trace=trace)
+                       dynamic_grouping=dynamic_grouping, trace=trace)
     for i, (p, (_, g)) in enumerate(zip(prompts, _REQS)):
         eng.submit(p, adapter="t0", max_new_tokens=g, key_id=i,
                    deadline_ticks=deadline if i == 3 else None)
@@ -447,6 +454,108 @@ class TestLifecycleEvents:
             assert sum(c.data["chunk_len"] for c in chunks) == P
             assert chunks[-1].data["final"] is True
             assert all(not c.data["final"] for c in chunks[:-1])
+
+
+# ---------------------------------------------------------------------------
+# Profiler spans: the same contract with jax.profiler on
+# ---------------------------------------------------------------------------
+
+# Every path the engine serves by, each through the fleet stack so that
+# ``engine.stack_insert`` fires on all three.
+_SPAN_PATHS = {
+    "paged": dict(paged=True, dynamic_grouping=True),
+    "rect": dict(dynamic_grouping=True),
+    "spec": dict(speculative_k=2, dynamic_grouping=True),
+}
+
+
+def _engine_spans(path: str) -> list[tuple[str, int, int, dict]]:
+    """(name, start ns, end ns, args) of every ``engine.*`` event on the
+    host plane of the profile under ``path``, parents before children."""
+    files = glob.glob(os.path.join(path, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert len(files) == 1, files
+    pd = jax.profiler.ProfileData.from_file(files[0])
+    out = [(ev.name, int(ev.start_ns), int(ev.end_ns), dict(ev.stats))
+           for plane in pd.planes if plane.name == "/host:CPU"
+           for line in plane.lines for ev in line.events
+           if ev.name.startswith("engine.")]
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+@pytest.fixture(scope="module", params=sorted(_SPAN_PATHS))
+def profiled(request, tmp_path_factory):
+    """One path served untraced, then with a TraceRecorder under
+    ``jax.profiler.trace``: (path name, untraced results, untraced engine,
+    traced results, traced engine, recorder, engine spans)."""
+    kw = _SPAN_PATHS[request.param]
+    off_res, off_eng = _drive(None, **kw)
+    rec = TraceRecorder()
+    d = tmp_path_factory.mktemp(f"spans_{request.param}")
+    with jax.profiler.trace(str(d)):
+        on_res, on_eng = _drive(rec, **kw)
+    return (request.param, off_res, off_eng, on_res, on_eng, rec,
+            _engine_spans(str(d)))
+
+
+def _ticks(spans):
+    """Each ``engine.tick`` span with the spans it holds, in start order."""
+    return [(t, [s for s in spans if s is not t and t[1] <= s[1]
+                 and s[2] <= t[2]])
+            for t in spans if t[0] == "engine.tick"]
+
+
+class TestProfilerSpans:
+    def test_profiling_changes_nothing(self, profiled):
+        _, off_res, off_eng, on_res, on_eng, _, spans = profiled
+        assert spans, "the profile holds no engine span"
+        assert _streams(on_res) == _streams(off_res)
+        assert on_eng.stats().as_dict() == off_eng.stats().as_dict()
+        assert on_eng.compile_counts() == off_eng.compile_counts()
+
+    def test_one_tick_span_per_tick_and_one_triple_per_decode(
+            self, profiled):
+        path_name, _, _, _, eng, _, spans = profiled
+        st = eng.stats()
+        ticks = _ticks(spans)
+        assert [t[3]["tick"] for t, _ in ticks] == list(range(st.steps))
+        # every other span lies inside exactly one tick
+        held = sorted(s for _, inner in ticks for s in inner)
+        assert held == sorted(s for s in spans if s[0] != "engine.tick")
+        names = [s[0] for s in spans]
+        runs = st.decode_steps + st.draft_steps + st.verify_steps
+        assert names.count("engine.decode") == runs
+        # one read-back and one sample per decode run and per completed
+        # admission (its first token)
+        assert names.count("engine.fetch") == runs + st.prefills
+        assert names.count("engine.sample") == runs + st.prefills
+        decode_ticks = [(t, inner) for t, inner in ticks
+                        if any(s[0] == "engine.decode" for s in inner)]
+        assert len(decode_ticks) == st.decode_steps + st.verify_steps
+        k = _SPAN_PATHS[path_name].get("speculative_k", 0)
+        for t, inner in decode_ticks:
+            seq = [s[0] for s in inner]
+            tail = seq[seq.index("engine.decode"):]
+            n = tail.count("engine.decode")
+            assert n in ((1, k + 1) if k else (1,)), (t, seq)
+            assert tail == ["engine.decode", "engine.fetch",
+                            "engine.sample"] * n + ["engine.deliver"], seq
+            rows = {s[3]["rows"] for s in inner if s[0] == "engine.decode"}
+            assert rows and min(rows) >= 1
+
+    def test_request_ids_join_the_recorder(self, profiled):
+        path_name, _, _, _, eng, rec, spans = profiled
+        chunks = [(s[3]["request_id"], s[3]["slot"], s[3]["start"],
+                   s[3]["tokens"]) for s in spans if s[0] == "engine.chunk"]
+        assert chunks == [(e.request_id, e.slot, e.data["start"],
+                           e.data["chunk_len"])
+                          for e in rec.events("chunk_prefill")]
+        assert bool(chunks) == _SPAN_PATHS[path_name].get("paged", False)
+        inserts = [(s[3]["request_id"], s[3]["slot"]) for s in spans
+                   if s[0] == "engine.stack_insert"]
+        assert len(inserts) == eng.stats().stack_inserts >= 1
+        admitted = {(e.request_id, e.slot) for e in rec.events("admitted")}
+        assert set(inserts) <= admitted
 
 
 class TestCacheEvents:
