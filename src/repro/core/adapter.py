@@ -500,10 +500,15 @@ def precompute_adapter_state(params, adapters, cfg: DoRAConfig, *,
         computed with the exact runtime eps (``act_dtype`` must match the
         activation dtype the model runs in, else g is not bitwise-equal to
         the recomputed one);
-      - ``"gsB"`` (``fold_gsb=True`` only) — fp32 [d_out, r] with g·s folded
-        into B, enabling the broadcast-free decode compose. Off by default
-        because the folded evaluation order differs from the canonical
-        ``s·lora``-first form by last-ulp rounding.
+      - ``"gsB"`` (``fold_gsb=True`` only) — [d_out, r] with g·s folded
+        into B, enabling the broadcast-free decode compose. The product is
+        formed in fp32 and stored in ``act_dtype`` (fp32 when None): the
+        up-projection dot reads it at the activation precision, so a bf16
+        model keeps one bf16 copy instead of re-rounding an fp32 one on
+        every call. Off by default because the folded evaluation order
+        differs from the canonical ``s·lora``-first form by rounding: a
+        last ulp at fp32, the bf16 rounding of ``gsB`` at bf16 (see
+        docs/numerics.md).
 
     Stacked leaves ([n_scan, ...] / experts) are handled by vmapping over
     the leading dims. The returned tree is for **serving only**: prefill
@@ -512,7 +517,8 @@ def precompute_adapter_state(params, adapters, cfg: DoRAConfig, *,
     contract — any update to A/B/m invalidates the cache, so rebuild the
     state after each training step before serving again).
     """
-    eps = _norm.dtype_eps(act_dtype if act_dtype is not None else _F32)
+    act_dtype = act_dtype if act_dtype is not None else _F32
+    eps = _norm.dtype_eps(act_dtype)
 
     def leaf_state(W, ad):
         if W.ndim > 2:
@@ -527,7 +533,7 @@ def precompute_adapter_state(params, adapters, cfg: DoRAConfig, *,
         out["g"] = jax.lax.stop_gradient(g)
         if fold_gsb:
             gsB = (g * cfg.scaling)[:, None] * ad["B"].astype(_F32)
-            out["gsB"] = jax.lax.stop_gradient(gsB)
+            out["gsB"] = jax.lax.stop_gradient(gsB.astype(act_dtype))
         return out
 
     def walk(p_node, a_node):

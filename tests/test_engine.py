@@ -14,6 +14,7 @@ docs/numerics.md).
 """
 from __future__ import annotations
 
+import dataclasses
 import os
 import subprocess
 import sys
@@ -41,8 +42,10 @@ DCFG = DoRAConfig(rank=4, alpha=8.0, mode="eager")
 ARCH = "qwen2-7b"
 
 
-def _setup(tenants=1):
+def _setup(tenants=1, dtype=None):
     mcfg = get_config(ARCH, smoke=True)
+    if dtype is not None:
+        mcfg = dataclasses.replace(mcfg, dtype=dtype)
     scfg = StepConfig(dora=DCFG)
     params, _, _ = build_state(mcfg, DCFG, 0)
     cache = AdapterStateCache.for_serving(mcfg, scfg)
@@ -828,8 +831,8 @@ class TestFleetServing:
     the STATIC-signature engine and to each request served alone."""
     ML = 14
 
-    def _fleet(self, tenants=3):
-        mcfg, scfg, params, cache = _setup(tenants=tenants)
+    def _fleet(self, tenants=3, dtype=None):
+        mcfg, scfg, params, cache = _setup(tenants=tenants, dtype=dtype)
         # distinct non-zero B per tenant: seed-built trees have B == 0,
         # so every tenant would otherwise stream identical tokens and a
         # mis-indexed fleet stack could never be caught.
@@ -930,6 +933,61 @@ class TestFleetServing:
                                    dynamic_grouping=True, paged=True)
         assert paged == plain
         assert e_paged.pool_stats()["used_blocks"] == 0
+
+    def test_bf16_fleet_stack_holds_bf16_gsb(self):
+        """A bf16 dynamic engine's fleet stack keeps every lane's folded
+        gsB in bf16 (no fp32 copy for the decode step to re-round per
+        token), beside fp32 g."""
+        mcfg, scfg, params, cache = self._fleet(dtype=jnp.bfloat16)
+        eng, _ = self._run(mcfg, scfg, params, cache, self._trace(mcfg),
+                           dynamic_grouping=True)
+        folded = [n for n in jax.tree.leaves(
+            eng._dyn_stack,
+            is_leaf=lambda n: isinstance(n, dict) and "gsB" in n)
+            if isinstance(n, dict)]
+        assert folded
+        for leaf in folded:
+            assert leaf["gsB"].dtype == jnp.bfloat16
+            assert leaf["gsB"].shape[1] == eng.slots
+            assert leaf["g"].dtype == jnp.float32
+
+    def test_bf16_dynamic_streams_match_alone_bitwise(self):
+        """The dynamic-vs-sequential contract at bf16: a mixed-tenant
+        trace through the fleet stack streams bitwise what each request
+        streams served alone with its tenant's folded state."""
+        mcfg, scfg, params, cache = self._fleet(dtype=jnp.bfloat16)
+        reqs = self._trace(mcfg)
+        _, dyn = self._run(mcfg, scfg, params, cache, reqs,
+                           dynamic_grouping=True)
+        for (p, g, a), (rid, toks) in zip(reqs, sorted(dyn.items())):
+            np.testing.assert_array_equal(
+                toks, _alone(mcfg, scfg, params, cache, p, g, self.ML, a),
+                err_msg=f"request {rid} under bf16 dynamic grouping "
+                        f"diverged from serving it alone")
+
+    def test_bf16_fleet_decode_logits_match_single_tenant_bitwise(self):
+        """One fleet decode step over the K-lane stack of bf16-folded
+        states: each row's logits are bitwise the single-tenant folded
+        decode of that row under its own tenant's state."""
+        from repro.core import stack_adapter_states
+        mcfg, scfg, params, cache = self._fleet(dtype=jnp.bfloat16)
+        states = [cache.get_state(params, cache.current_handle(f"t{t}"))
+                  for t in range(3)]
+        rows = 3
+        cache_tree = init_cache(mcfg, rows, 8, row_lens=True)
+        toks = jnp.asarray(np.random.default_rng(5).integers(
+            0, mcfg.vocab_size, (rows, 1)), jnp.int32)
+        idx = np.array([2, 0, 1], np.int32)
+        fleet, _ = jax.jit(make_decode_step(
+            mcfg, scfg, batch=rows, dynamic_groups=True))(
+            params, stack_adapter_states(states, axis=1), cache_tree,
+            {"tokens": toks, "adapter_idx": jnp.asarray(idx)})
+        single = jax.jit(make_decode_step(mcfg, scfg, batch=rows))
+        for b, k in enumerate(idx):
+            want, _ = single(params, states[k], cache_tree, {"tokens": toks})
+            np.testing.assert_array_equal(
+                np.asarray(fleet[b]), np.asarray(want[b]),
+                err_msg=f"row {b} (tenant {k}) of the bf16 fleet decode")
 
     def test_max_active_per_adapter_prevents_starvation(self):
         """SATELLITE: a hot tenant's burst is rate-limited to its slot

@@ -5,6 +5,8 @@ kwarg forwarding.
 """
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -152,6 +154,39 @@ class TestCachedG:
         refolded = precompute_adapter_state(W, folded, self.DCFG,
                                             fold_gsb=False)
         assert "gsB" not in refolded and "g" in refolded
+
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                             ids=["bf16", "fp32"])
+    def test_folded_gsb_is_stored_in_the_activation_dtype(self, dtype):
+        """The model-level precompute folds gsB in fp32 and stores it in
+        the model's activation dtype: for a bf16 model, bitwise the fp32
+        fold rounded once to bf16; for an fp32 model, bitwise the fp32
+        fold. g stays fp32 either way."""
+        mcfg = dataclasses.replace(get_config(ARCH, smoke=True), dtype=dtype)
+        scfg = StepConfig(dora=self.DCFG)
+        params, adapters, _ = build_state(mcfg, self.DCFG, 0)
+        # seed-built trees have B == 0, which would fold to zeros
+        key = jax.random.PRNGKey(21)
+        paths, treedef = jax.tree_util.tree_flatten_with_path(adapters)
+        adapters = jax.tree_util.tree_unflatten(treedef, [
+            (0.2 * jax.random.normal(jax.random.fold_in(key, i), leaf.shape)
+             ).astype(leaf.dtype)
+            if jax.tree_util.keystr(path).endswith("['B']") else leaf
+            for i, (path, leaf) in enumerate(paths)])
+        served = jax.jit(make_precompute_step(mcfg, scfg, fold_gsb=True))(
+            params, adapters)
+        folded = [n for n in jax.tree.leaves(
+            served, is_leaf=lambda n: isinstance(n, dict) and "gsB" in n)
+            if isinstance(n, dict)]
+        assert folded
+        for leaf in folded:
+            assert leaf["gsB"].dtype == jnp.dtype(dtype)
+            assert leaf["g"].dtype == jnp.float32
+            fold32 = ((leaf["g"] * self.DCFG.scaling)[..., None]
+                      * leaf["B"].astype(jnp.float32))
+            assert np.any(np.asarray(fold32) != 0)
+            np.testing.assert_array_equal(
+                np.asarray(leaf["gsB"]), np.asarray(fold32.astype(dtype)))
 
     def test_gsb_fast_path_runs_under_sharding_constraint(self):
         """Sharded call sites used to fall off the broadcast-free decode

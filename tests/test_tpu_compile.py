@@ -13,6 +13,7 @@ only the worker that runs this file may do so.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -134,3 +135,59 @@ def test_paged_gather(one_chip):
         one_chip, ((n_blocks, bs, KV_HEADS, HEAD_DIM), BF16),
         ((slots, max_len // bs), jnp.int32))
     _assert_kernel(text, "paged_gather")
+
+
+def _entry_converts(text: str) -> list[str]:
+    """Output shapes (``bf16[2,8,3584,384]``) of the ``convert`` ops at the
+    top level of a compiled module's entry computation."""
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    return re.findall(r"= (\w+\[[\d,]*\])\S* convert\(", entry)
+
+
+def test_fleet_decode_reads_the_stack_without_converting_it(one_chip):
+    """The fleet decode step (K-lane stack of folded serving states, one
+    traced lane index per row) for a bf16 model, at qwen2-7b widths with
+    two scanned layers. The folded ``gsB`` is stored in the activation
+    dtype, so the step feeds the stack to its dot as it is: no entry-level
+    ``convert`` rewrites a ``[n_scan, K, d_out, r]`` stack on every
+    token."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.core import DoRAConfig, stack_adapter_states
+    from repro.launch.steps import (StepConfig, make_decode_step,
+                                    make_precompute_step)
+    from repro.models.lm import adapter_shapes, cache_shapes, param_shapes
+
+    lanes, slots, max_len = 8, 8, 256
+    mcfg = dataclasses.replace(get_config("qwen2-7b"), num_layers=2,
+                               vocab_size=4096, dtype=BF16)
+    scfg = StepConfig(dora=DoRAConfig(rank=RANK, alpha=192.0, rslora=True))
+    params = param_shapes(mcfg)
+    state = jax.eval_shape(
+        make_precompute_step(mcfg, scfg, fold_gsb=True), params,
+        adapter_shapes(mcfg, scfg.dora))
+    stack = jax.eval_shape(
+        lambda s: stack_adapter_states([s] * lanes, axis=1), state)
+    gsb = {tuple(l.shape) for l in jax.tree.leaves(
+        jax.tree.map(lambda leaf: leaf["gsB"], stack["stack"],
+                     is_leaf=lambda n: isinstance(n, dict) and "gsB" in n))}
+    assert gsb == {(2, lanes, d, RANK)
+                   for d in (D_MODEL, D_FF, KV_HEADS * HEAD_DIM)}, gsb
+    on_chip = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        tree)
+    batch_in = {"tokens": jax.ShapeDtypeStruct((slots, 1), jnp.int32),
+                "adapter_idx": jax.ShapeDtypeStruct((slots,), jnp.int32)}
+    text = jax.jit(make_decode_step(mcfg, scfg, batch=slots,
+                                    dynamic_groups=True)).lower(
+        on_chip(params), on_chip(stack),
+        on_chip(cache_shapes(mcfg, slots, max_len, row_lens=True)),
+        on_chip(batch_in)).compile().as_text()
+    stack_dims = {",".join(map(str, shape)) for shape in gsb}
+    stack_shaped = [s for s in _entry_converts(text)
+                    if s[s.index("[") + 1:-1] in stack_dims]
+    assert not stack_shaped, (
+        f"the decode step converts the fleet stack's folded gsB on every "
+        f"token: {stack_shaped}")
