@@ -1,4 +1,4 @@
-"""Architecture registry: the 10 assigned configs (+ the paper's own
+"""Architecture registry: the assigned configs (+ the paper's own
 Qwen2-VL-7B proxy), each with a FULL config (exact published numbers, dry-run
 only) and a SMOKE config (reduced, runs a real step on CPU)."""
 from __future__ import annotations
@@ -19,6 +19,7 @@ ARCH_IDS = [
     "qwen2-7b",
     "qwen2-vl-72b",
     "musicgen-medium",
+    "jamba2-3b",
 ]
 
 EXTRA_IDS = ["qwen2-vl-7b"]  # paper-native proxy (benchmarks only)
@@ -35,6 +36,7 @@ _MODULES = {
     "qwen2-vl-72b": "qwen2_vl_72b",
     "musicgen-medium": "musicgen_medium",
     "qwen2-vl-7b": "qwen2_vl_7b",
+    "jamba2-3b": "jamba2_3b",
 }
 
 

@@ -233,6 +233,20 @@ def plan_gather(cfg: DoRAConfig | None, *, head_elems: int) -> KernelPlan:
     return KernelPlan(Tier.FUSED_FWD, backend.name, backend.interpret)
 
 
+def plan_scan(cfg: DoRAConfig | None, *, d_inner: int) -> KernelPlan:
+    """Resolve the Mamba selective-scan call site
+    (``repro.kernels.selective_scan``): the Pallas forward/backward pair
+    on a fused tier, the XLA chunked scan otherwise. ``d_inner`` is the
+    kernels' 128-lane axis. ``cfg=None`` (a base model with no adapter
+    config) takes the XLA scan, which computes the same recurrence."""
+    if cfg is None or not shape_supported(d_inner):
+        return KernelPlan(Tier.EAGER, "eager", False)
+    backend = resolve_backend(cfg)
+    if not backend.fused or unpartitionable(backend):
+        return KernelPlan(Tier.EAGER, "eager", False)
+    return KernelPlan(Tier.FUSED_BWD, backend.name, backend.interpret)
+
+
 def select_tier(cfg: DoRAConfig, *, training: bool, rows: int,
                 d_out: int) -> Tier:
     return plan_compose(cfg, training=training, rows=rows,

@@ -1,6 +1,6 @@
 """Sharding rules for the production mesh, with divisibility fallback.
 
-Strategy (DESIGN.md §5):
+Strategy:
 
   - **Base weights**: TP over ``model`` on the hidden/head dim + FSDP over
     (``pod``, ``data``) on the other dim. Frozen → no optimizer state, no
@@ -155,6 +155,11 @@ def leaf_roles(mcfg: ModelConfig, name: str, ndim: int, mesh) \
         "skip_d": ("tp",),
         "conv_w": ("repl", "tp"),
         "conv_b": ("tp",),
+        # Jamba's inner norms: scales of the dt_rank / state dims, which
+        # no TP axis splits
+        "dt_norm": ("repl",),
+        "b_norm": ("repl",),
+        "c_norm": ("repl",),
     }
     if name in table:
         roles = table[name]
@@ -193,9 +198,9 @@ def adapter_sharding(mcfg: ModelConfig, dcfg: DoRAConfig, mesh,
     (A col-sharded like W's d_in, B/m row-sharded like W's d_out); the
     rank dim replicates. At r = 384 on a 30-70B model the adapters are
     multi-GB, so — unlike low-rank LoRA — they cannot be DP-replicated on
-    16 GB chips; the factored norm's distributed accumulation (DESIGN.md
-    §5, the paper's FSDP2 future-work item) is what makes the d_in
-    sharding of A/W work without an all-gather.
+    16 GB chips; the factored norm's distributed accumulation (the
+    paper's FSDP2 future-work item) is what makes the d_in sharding of
+    A/W work without an all-gather.
 
     ``serving=True`` additionally emits the frozen-adapter serving-state
     leaves written by ``precompute_adapter_state``: ``"g"`` [n_scan,

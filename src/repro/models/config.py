@@ -57,8 +57,11 @@ class ModelConfig:
     ssm_expand: int = 2
     dt_rank: int = 0                # 0 → ceil(d_model / 16)
     ssm_chunk: int = 256            # chunked-scan length (assoc impl)
+    # Jamba's mixer: RMSNorm with learned scales on dt, B and C after
+    # x_proj (HF JambaMambaMixer dt/b/c_layernorm).
+    ssm_inner_norm: bool = False
     # "fused_chunk": w-unrolled recurrence per scan chunk, a/b computed on
-    #   the fly (traffic-optimal; see EXPERIMENTS.md §Perf cell 1).
+    #   the fly (never materialized at [B, S, di, n]).
     # "assoc": full-S a/b materialization + associative_scan (baseline).
     ssm_impl: str = "fused_chunk"
     ssm_unroll: int = 16            # tokens per unrolled chunk (fused)
@@ -152,6 +155,8 @@ class ModelConfig:
                 total += (2 * di * D + di * self.ssm_conv + di
                           + (dtr + 2 * n) * di + di * dtr + di
                           + di * n + di + D * di)
+                if self.ssm_inner_norm:
+                    total += dtr + 2 * n
             if fk != "none":
                 total += D  # ln2
             if fk == "mlp":

@@ -146,6 +146,12 @@ def _causal_mask_bias(q_len: int, kv_len: int, offset, dtype):
     return jnp.where(ok, 0.0, -1e30).astype(_F32)
 
 
+# Self-attention over more than LONG_SEQ positions (training) runs in
+# query blocks of Q_BLOCK rows (see ``attention_core``).
+LONG_SEQ = 4096
+Q_BLOCK = 512
+
+
 def attention_core(q, k, v, *, offset=0, chunk: int | None = None):
     """Grouped-query attention core. q: [B,S,Hq,hd]; k/v: [B,T,Hkv,hd].
     KV heads are never materialized repeated: queries are reshaped to
@@ -178,6 +184,22 @@ def attention_core(q, k, v, *, offset=0, chunk: int | None = None):
         out = jnp.einsum("bkgst,btkh->bskgh", probs.astype(q.dtype), v,
                          preferred_element_type=_F32)
         return out.reshape(b, s, hq, hd).astype(q.dtype)
+
+    if isinstance(offset, int) and offset == 0 and s == t \
+            and s > LONG_SEQ and s % Q_BLOCK == 0:
+        # Long self-attention (training): query blocks of Q_BLOCK rows,
+        # each over the keys up to its own end (fully masked keys are
+        # skipped) in the online-softmax chunks below, and recomputed in
+        # the backward. A block's softmax carries are [Q_BLOCK, hd] per
+        # key chunk, where one pass over all rows would keep [S, hd] per
+        # key chunk for the whole backward.
+        outs = []
+        for start in range(0, s, Q_BLOCK):
+            end = start + Q_BLOCK
+            block = jax.checkpoint(functools.partial(
+                attention_core, offset=start, chunk=chunk))
+            outs.append(block(q[:, start:end], k[:, :end], v[:, :end]))
+        return jnp.concatenate(outs, axis=1)
 
     # Online softmax over KV chunks (flash-style, lax.scan over chunks).
     if jnp.ndim(offset) != 0:
@@ -277,8 +299,8 @@ def attention(x, params, dora, mcfg, dcfg: DoRAConfig | None, *,
     # NOTE (H3.4, refuted): pinning q/k/v to head-parallel sharding here
     # was measured to INCREASE collective time — with kv_heads < tp the
     # replicated K/V gradients partial-sum over the model axis and the
-    # gathered-sequence backward adds a second reshard (EXPERIMENTS.md
-    # §Perf cell 3). GSPMD's own choice (a2a on score tiles) is cheaper.
+    # gathered-sequence backward adds a second reshard. GSPMD's own choice
+    # (a2a on score tiles) is cheaper.
 
     if cache is None:
         out = attention_core(q, k, v, offset=0, chunk=mcfg.attn_chunk)

@@ -17,6 +17,7 @@ batch row stands at its own position and requests join/leave mid-decode.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
@@ -68,12 +69,16 @@ def _attn_spec(mcfg: ModelConfig):
 def _mamba_spec(mcfg: ModelConfig):
     D, di, n = mcfg.d_model, mcfg.d_inner, mcfg.ssm_state
     dtr, k = mcfg.dt_rank, mcfg.ssm_conv
-    return {"in_proj": ("linear", (2 * di, D)),
-            "conv_w": ("conv", (k, di)), "conv_b": ("zeros", (di,)),
-            "x_proj": ("linear", (dtr + 2 * n, di)),
-            "dt_proj": ("linear", (di, dtr)), "dt_bias": ("dt_bias", (di,)),
-            "A_log": ("a_log", (di, n)), "skip_d": ("ones", (di,)),
-            "out_proj": ("linear", (D, di))}
+    s = {"in_proj": ("linear", (2 * di, D)),
+         "conv_w": ("conv", (k, di)), "conv_b": ("zeros", (di,)),
+         "x_proj": ("linear", (dtr + 2 * n, di)),
+         "dt_proj": ("linear", (di, dtr)), "dt_bias": ("dt_bias", (di,)),
+         "A_log": ("a_log", (di, n)), "skip_d": ("ones", (di,)),
+         "out_proj": ("linear", (D, di))}
+    if mcfg.ssm_inner_norm:
+        s.update(dt_norm=("ones", (dtr,)), b_norm=("ones", (n,)),
+                 c_norm=("ones", (n,)))
+    return s
 
 
 def _mlp_spec(mcfg: ModelConfig, ff: int):
@@ -368,7 +373,7 @@ def _layer_apply(x, p, a, c, mcfg, dcfg, *, kind, ffn, positions, length,
     c: None (no cache) or this layer's cache dict. Returns (x, new_cache,
     aux_loss). ``constrain`` pins the sublayer outputs to the
     sequence-parallel sharding so the row-parallel TP partial sums lower
-    to reduce-scatter instead of all-reduce (EXPERIMENTS.md §Perf H1.4).
+    to reduce-scatter instead of all-reduce.
     ``tenant_groups``: multi-tenant serving — adapted linears apply the
     per-group folded adapter state (attention/dense-MLP archs only)."""
     aux = jnp.asarray(0.0, _F32)
@@ -486,18 +491,32 @@ def forward(mcfg: ModelConfig, params, adapters, dcfg: DoRAConfig | None,
     if boundary_constraint is not None:
         x = boundary_constraint(x)
 
+    # remat="layer" keeps only layer boundaries (and the named DoRA norms)
+    # for the backward: a one-layer scan unit is checkpointed whole, the
+    # layers of a multi-layer unit (a hybrid's period) one by one, so the
+    # backward holds one layer's activations at a time, not the period's.
+    remat = functools.partial(
+        jax.checkpoint,
+        policy=jax.checkpoint_policies.save_only_these_names("dora_wnorm"))
+
+    def layer_apply(x, p_i, a_i, c_i, kind, ffn):
+        return _layer_apply(
+            x, p_i, a_i, c_i, mcfg, dcfg, kind=kind, ffn=ffn,
+            positions=positions, length=length, training=training,
+            constrain=boundary_constraint, tenant_groups=tenant_groups,
+            pages=pages)
+
+    if mcfg.remat == "layer" and p > 1:
+        layer_apply = remat(layer_apply, static_argnums=(4, 5))
+
     def unit_body(x, unit_p, unit_a, unit_c):
         aux_total = jnp.asarray(0.0, _F32)
         new_cs = {}
         for i in range(p):
             li = f"l{i}"
             c_i = unit_c[li] if unit_c is not None else None
-            x, new_c, aux = _layer_apply(
-                x, unit_p[li], unit_a.get(li), c_i, mcfg, dcfg,
-                kind=kinds[i], ffn=ffns[i], positions=positions,
-                length=length, training=training,
-                constrain=boundary_constraint,
-                tenant_groups=tenant_groups, pages=pages)
+            x, new_c, aux = layer_apply(x, unit_p[li], unit_a.get(li), c_i,
+                                        kinds[i], ffns[i])
             if new_c is not None:
                 new_cs[li] = new_c
             aux_total = aux_total + aux
@@ -505,11 +524,8 @@ def forward(mcfg: ModelConfig, params, adapters, dcfg: DoRAConfig | None,
             x = boundary_constraint(x)
         return x, new_cs, aux_total
 
-    if mcfg.remat == "layer":
-        unit_body = jax.checkpoint(
-            unit_body,
-            policy=jax.checkpoint_policies.save_only_these_names(
-                "dora_wnorm"))
+    if mcfg.remat == "layer" and p == 1:
+        unit_body = remat(unit_body)
 
     if stack_c is None:
         def body(carry, xs):

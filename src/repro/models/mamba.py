@@ -4,11 +4,19 @@ TPU adaptation notes:
   - The depthwise causal conv (k=4) is expressed as a sum of shifted slices
     (4 adds) instead of a grouped convolution — elementwise in d_inner, so it
     shards cleanly over the model axis.
-  - The selective scan runs chunked: ``jax.lax.scan`` carries the [B, di, n]
-    state across chunks of ``ssm_chunk`` tokens; within a chunk the linear
-    recurrence h_t = a_t h_{t-1} + b_t is a ``jax.lax.associative_scan`` over
-    the chunk (parallel prefix — maps to the VPU, avoids the [B,S,di,n]
-    full-sequence materialization).
+  - The selective scan: on a TPU (the DoRA kernel dispatch's compiled or
+    interpret tier) the Pallas kernel pair of ``kernels/selective_scan.py``,
+    forward and backward, which keeps states in VMEM and saves only the
+    chunk-start states for the backward. Elsewhere ``_ssm_scan_fused``: a
+    ``jax.lax.scan`` over chunks of ``ssm_unroll`` tokens whose body runs
+    the recurrence unrolled. ``ssm_impl="assoc"`` keeps the
+    associative-scan formulation as a baseline.
+  - Jamba's mixer (``ssm_inner_norm``) RMS-normalizes dt, B and C after
+    ``x_proj``, each with a learned scale, before ``dt_proj``.
+  - Training over a long sequence runs the mixer between its projections
+    in checkpointed blocks of ``SEQ_BLOCK`` tokens, carrying the state and
+    the conv's inputs across blocks, so the backward holds one block's
+    float32 intermediates at a time.
   - Everything between in_proj and out_proj is elementwise (or contracts only
     dt_rank/state dims), so d_inner is the natural TP axis: in_proj
     row-sharded, out_proj col-sharded (psum), scan state sharded on di.
@@ -17,14 +25,22 @@ Decode carries {"h": [B, di, n], "conv": [B, k-1, di]} per layer.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 from repro.core import DoRAConfig
+from repro.core.dispatch import plan_scan
+from repro.kernels.selective_scan import selective_scan
 from repro.models import layers as L
 from repro.models.config import ModelConfig
 
 _F32 = jnp.float32
+# Training over more than SEQ_BLOCK tokens runs the mixer's core (conv to
+# gated scan output) in checkpointed blocks of this many tokens
+# (``_core_in_blocks``).
+SEQ_BLOCK = 4096
 
 
 def _causal_conv(x, w, b, cache=None):
@@ -54,16 +70,16 @@ def _ssm_scan_fused(dt, dtx, Bm, Cm, A, h0, w: int):
     dt, dtx: [B, S, di] fp32; Bm, Cm: [B, S, n] fp32; A: [di, n];
     h0: [B, di, n]. Returns (y [B, S, di], h_final).
 
-    Traffic-optimal XLA formulation (EXPERIMENTS.md §Perf cell 1): a
-    ``lax.scan`` over S/w chunks whose body runs w UNROLLED recurrence
-    steps — one fusion that reads the [B, w, di] / [B, w, n] slices once,
-    keeps h and the [B, di, n] discretized terms in registers, and writes
-    y once. The full-sequence [B, S, di, n] tensors a/b are never
-    materialized (the associative-scan formulation materialized them plus
-    O(log chunk) tree levels of the same size — ~550x the per-tensor
-    bytes in HBM traffic). This is the same schedule the Pallas
-    selective-scan kernel pins on TPU (kernels/selective_scan.py); the
-    XLA version keeps the dry-run honest on CPU.
+    Traffic-optimal XLA formulation: a ``lax.scan`` over S/w chunks whose
+    body runs w UNROLLED recurrence steps — one fusion that reads the
+    [B, w, di] / [B, w, n] slices once, keeps h and the [B, di, n]
+    discretized terms in registers, and writes y once. The full-sequence
+    [B, S, di, n] tensors a/b are never materialized (the associative-scan
+    formulation materialized them plus O(log chunk) tree levels of the
+    same size — ~550x the per-tensor bytes in HBM traffic). This is the
+    same schedule the Pallas selective-scan kernel pins on TPU
+    (kernels/selective_scan.py); the XLA version keeps the dry-run honest
+    on CPU.
     """
     B, S, di = dt.shape
     n = A.shape[1]
@@ -110,10 +126,9 @@ def _ssm_scan(a, b, C, h0, chunk: int):
     Chunked: lax.scan over S/chunk chunks, associative_scan inside.
     Returns (y [B, S, di] fp32, h_final [B, di, n]).
 
-    NOTE: kept as the ``ssm_impl="assoc"`` baseline for the §Perf
-    ablation; the default path is ``_ssm_scan_fused`` (see EXPERIMENTS.md
-    §Perf cell 1 — this formulation's associative-scan tree costs ~550x
-    the tensor bytes in HBM traffic under XLA's lowering).
+    NOTE: kept as the ``ssm_impl="assoc"`` baseline; the default path is
+    ``_ssm_scan_fused`` (this formulation's associative-scan tree
+    materializes several [B, S, di, n] levels under XLA's lowering).
     """
     B, S, di, n = a.shape
     if S == 1:  # decode fast path
@@ -150,20 +165,12 @@ def _ssm_scan(a, b, C, h0, chunk: int):
     return y[:, :S], h_f
 
 
-def mamba_block(x, p, dora, mcfg: ModelConfig, dcfg: DoRAConfig | None, *,
-                cache=None, training: bool = True, constrain=None):
-    """x [B, S, D] → (y [B, S, D], new_cache).
-
-    p: {"in_proj": [2di, D], "conv_w": [k, di], "conv_b": [di],
-        "x_proj": [dtr+2n, di], "dt_proj": [di, dtr], "dt_bias": [di],
-        "A_log": [di, n], "skip_d": [di], "out_proj": [D, di]}.
-    """
-    B, S, D = x.shape
+def _mixer_core(xz, p, cache, *, mcfg, dcfg):
+    """The mixer between its two projections: xz [B, S, 2di] (in_proj's
+    output) → (y [B, S, di] gated, h_final, the conv's last inputs), from
+    the state and conv inputs in ``cache`` (None: from zero)."""
+    B, S = xz.shape[:2]
     di, n, dtr = mcfg.d_inner, mcfg.ssm_state, mcfg.dt_rank
-    dora = dora or {}
-
-    xz = L.maybe_dora(x, p["in_proj"], dora.get("in_proj"), dcfg,
-                      training=training)                       # [B,S,2di]
     xi, z = jnp.split(xz, 2, axis=-1)
 
     conv_cache = cache["conv"] if cache is not None else None
@@ -175,6 +182,11 @@ def mamba_block(x, p, dora, mcfg: ModelConfig, dcfg: DoRAConfig | None, *,
     sg = jax.lax.stop_gradient
     bcdt = xi @ sg(p["x_proj"]).T                              # [B,S,dtr+2n]
     dt_in, Bm, Cm = jnp.split(bcdt.astype(_F32), [dtr, dtr + n], axis=-1)
+    if mcfg.ssm_inner_norm:
+        eps = mcfg.norm_eps
+        dt_in = L.rms_norm(dt_in, sg(p["dt_norm"]), eps)
+        Bm = L.rms_norm(Bm, sg(p["b_norm"]), eps)
+        Cm = L.rms_norm(Cm, sg(p["c_norm"]), eps)
     dt = jax.nn.softplus(dt_in @ sg(p["dt_proj"]).astype(_F32).T
                          + sg(p["dt_bias"]).astype(_F32))      # [B,S,di]
 
@@ -187,11 +199,58 @@ def mamba_block(x, p, dora, mcfg: ModelConfig, dcfg: DoRAConfig | None, *,
         y, h_f = _ssm_scan(a, b, Cm, h0, mcfg.ssm_chunk)
     else:
         dtx = dt * xi.astype(_F32)                             # [B,S,di]
-        y, h_f = _ssm_scan_fused(dt, dtx, Bm, Cm, A, h0,
-                                 mcfg.ssm_unroll)
+        plan = plan_scan(dcfg, d_inner=di)
+        if S > 1 and plan.fused:
+            y, h_f = selective_scan(dt, dtx, Bm, Cm, A, h0,
+                                    interpret=plan.interpret)
+        else:
+            y, h_f = _ssm_scan_fused(dt, dtx, Bm, Cm, A, h0,
+                                     mcfg.ssm_unroll)
     y = y + sg(p["skip_d"]).astype(_F32) * xi.astype(_F32)
     y = y * jax.nn.silu(z.astype(_F32))
-    y = y.astype(x.dtype)
+    y = y.astype(xz.dtype)
+
+    return y, h_f, new_conv
+
+
+def _core_in_blocks(core, xz, p, mcfg):
+    """``core`` over the sequence in blocks of SEQ_BLOCK tokens, each
+    checkpointed, the state and the conv's last inputs carried from block
+    to block: the backward holds one block's float32 [B, SEQ_BLOCK, di]
+    intermediates at a time, not the whole sequence's."""
+    B = xz.shape[0]
+    carry = (jnp.zeros((B, mcfg.d_inner, mcfg.ssm_state), _F32),
+             jnp.zeros((B, mcfg.ssm_conv - 1, mcfg.d_inner), xz.dtype))
+    block = jax.checkpoint(core)
+    ys = []
+    for start in range(0, xz.shape[1], SEQ_BLOCK):
+        y, h, conv = block(xz[:, start:start + SEQ_BLOCK], p,
+                           {"h": carry[0], "conv": carry[1]})
+        carry = (h, conv)
+        ys.append(y)
+    return jnp.concatenate(ys, axis=1), carry[0], carry[1]
+
+
+def mamba_block(x, p, dora, mcfg: ModelConfig, dcfg: DoRAConfig | None, *,
+                cache=None, training: bool = True, constrain=None):
+    """x [B, S, D] → (y [B, S, D], new_cache).
+
+    p: {"in_proj": [2di, D], "conv_w": [k, di], "conv_b": [di],
+        "x_proj": [dtr+2n, di], "dt_proj": [di, dtr], "dt_bias": [di],
+        "A_log": [di, n], "skip_d": [di], "out_proj": [D, di]}, and with
+        ``ssm_inner_norm`` the scales "dt_norm": [dtr], "b_norm": [n],
+        "c_norm": [n].
+    """
+    S = x.shape[1]
+    dora = dora or {}
+
+    xz = L.maybe_dora(x, p["in_proj"], dora.get("in_proj"), dcfg,
+                      training=training)                       # [B,S,2di]
+    core = functools.partial(_mixer_core, mcfg=mcfg, dcfg=dcfg)
+    if cache is None and training and S > SEQ_BLOCK and S % SEQ_BLOCK == 0:
+        y, h_f, new_conv = _core_in_blocks(core, xz, p, mcfg)
+    else:
+        y, h_f, new_conv = core(xz, p, cache)
 
     # row-parallel projection: constrain output to SP sharding (H1.4)
     out = L.maybe_dora(y, p["out_proj"], dora.get("out_proj"), dcfg,

@@ -114,3 +114,23 @@ def test_param_shapes_match_init():
     a = jax.tree.map(lambda s: (s.shape, s.dtype), csh)
     b = jax.tree.map(lambda x: (x.shape, x.dtype), c)
     assert a == b
+
+
+def test_jamba2_param_and_adapter_shapes_match_init():
+    """jamba2-3b SMOKE: Jamba's inner-norm scales are in the spec at their
+    shapes, and x_proj / dt_proj (narrower than a production rank) carry
+    no adapter."""
+    mcfg = get_config("jamba2-3b", smoke=True)
+    assert mcfg.ssm_inner_norm and mcfg.layer_kinds()[7] == "attn"
+    params = init_params(jax.random.PRNGKey(0), mcfg)
+    a = jax.tree.map(lambda s: (s.shape, s.dtype), param_shapes(mcfg))
+    assert a == jax.tree.map(lambda x: (x.shape, x.dtype), params)
+    mixer = params["stack"]["l0"]["mixer"]
+    assert mixer["dt_norm"].shape == (1, mcfg.dt_rank)
+    assert mixer["b_norm"].shape == mixer["c_norm"].shape == (
+        1, mcfg.ssm_state)
+    ad = init_adapters(jax.random.PRNGKey(1), mcfg, params, DCFG)
+    a = jax.tree.map(lambda s: (s.shape, s.dtype), adapter_shapes(mcfg, DCFG))
+    assert a == jax.tree.map(lambda x: (x.shape, x.dtype), ad)
+    assert set(ad["stack"]["l0"]["mixer"]) == {"in_proj", "out_proj"}
+    assert set(ad["stack"]["l7"]["mixer"]) == {"wq", "wk", "wv", "wo"}

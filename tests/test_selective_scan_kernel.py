@@ -92,3 +92,162 @@ def test_kernel_matches_fused_chunk_xla():
                                rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(np.asarray(h_k).transpose(0, 2, 1),
                                np.asarray(h_x), rtol=2e-5, atol=2e-5)
+
+
+def _sequential(dt, dtx, Bm, Cm, A, h0):
+    """Token-by-token float32 recurrence in the model's layout, for
+    ``jax.grad``."""
+    def step(h, xs):
+        dt_t, u_t, b_t, c_t = xs
+        h = jnp.exp(dt_t[..., None] * A) * h + u_t[..., None] \
+            * b_t[:, None, :]
+        return h, jnp.einsum("bdn,bn->bd", h, c_t)
+    xs = tuple(jnp.moveaxis(t, 1, 0) for t in (dt, dtx, Bm, Cm))
+    h_f, ys = jax.lax.scan(step, h0, xs)
+    return jnp.moveaxis(ys, 0, 1), h_f
+
+
+@pytest.mark.parametrize("B,S,di,chunk", [
+    (1, 32, 256, 8),     # 4 chunks, 2 d_inner tiles
+    (2, 20, 128, 8),     # S padded to 24 with no-op tokens
+])
+def test_scan_vjp_matches_grad_of_sequential_scan(B, S, di, chunk):
+    """Forward and every cotangent of the custom-VJP kernel pair (dt, dtx,
+    B, C, A, h0) against ``jax.grad`` of the sequential scan, with a
+    nonzero h0 and cotangents on both y and h_final."""
+    from repro.kernels.selective_scan import selective_scan
+    n = 16
+    dt, xi, Bm, Cm, A, h0 = _inputs(jax.random.PRNGKey(3), B, S, di, n)
+    dtx = dt * xi
+    k = jax.random.PRNGKey(4)
+    wy = jax.random.normal(k, (B, S, di), _F32)
+    wh = jax.random.normal(jax.random.fold_in(k, 1), (B, di, n), _F32)
+
+    def loss(fn):
+        def f(*args):
+            y, h_f = fn(*args)
+            return jnp.sum(y * wy) + jnp.sum(h_f * wh)
+        return f
+
+    kern = lambda *a: selective_scan(*a, chunk=chunk, block_di=128,
+                                     interpret=True)
+    args = (dt, dtx, Bm, Cm, A, h0)
+    (y_k, h_k), (y_r, h_r) = kern(*args), _sequential(*args)
+    np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_r),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(h_k), np.asarray(h_r),
+                               rtol=2e-5, atol=2e-5)
+    g_k = jax.grad(loss(kern), argnums=range(6))(*args)
+    g_r = jax.grad(loss(_sequential), argnums=range(6))(*args)
+    for name, a, b in zip(("dt", "dtx", "B", "C", "A", "h0"), g_k, g_r):
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-5 * scale,
+                                   err_msg=name)
+
+
+def test_backward_kernel_writes_per_tile_partials_of_b_and_c():
+    """d_inner tiles of the backward add up: one 256-wide tile and two
+    128-wide tiles give the same dB and dC."""
+    from repro.kernels.selective_scan import (selective_scan_bwd_pallas,
+                                              selective_scan_pallas)
+    B, S, di, n, chunk = 1, 16, 256, 8, 8
+    dt, xi, Bm, Cm, A, h0 = _inputs(jax.random.PRNGKey(5), B, S, di, n)
+    args = (dt, dt * xi, Bm, Cm, A.T, jnp.swapaxes(h0, 1, 2))
+    _, _, hs = selective_scan_pallas(*args, chunk=chunk, save_states=True,
+                                     interpret=True)
+    dy = jax.random.normal(jax.random.PRNGKey(6), (B, S, di), _F32)
+    dh = jnp.zeros((B, n, di), _F32)
+    one, two = (selective_scan_bwd_pallas(*args[:5], hs, dy, dh,
+                                          block_di=bd, chunk=chunk,
+                                          interpret=True)
+                for bd in (256, 128))
+    for a, b in zip(one, two):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_saved_states_are_the_state_at_each_chunk_start():
+    from repro.kernels.selective_scan import selective_scan_pallas
+    B, S, di, n, chunk = 1, 12, 128, 4, 4
+    dt, xi, Bm, Cm, A, h0 = _inputs(jax.random.PRNGKey(7), B, S, di, n)
+    _, _, hs = selective_scan_pallas(
+        dt, dt * xi, Bm, Cm, A.T, jnp.swapaxes(h0, 1, 2), chunk=chunk,
+        save_states=True, interpret=True)
+    np.testing.assert_array_equal(np.asarray(hs[:, 0]),
+                                  np.asarray(jnp.swapaxes(h0, 1, 2)))
+    for c in range(1, S // chunk):
+        _, h = _reference(dt[:, :c * chunk], xi[:, :c * chunk],
+                          Bm[:, :c * chunk], Cm[:, :c * chunk], A, h0)
+        np.testing.assert_allclose(np.asarray(hs[:, c]).transpose(0, 2, 1),
+                                   h, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("tier,fused", [("interpret", True),
+                                        ("eager", False)])
+def test_mamba_block_takes_the_kernel_pair_on_a_fused_tier(tier, fused,
+                                                           monkeypatch):
+    """The mixer's scan goes through the Pallas pair where the dispatch
+    picks a fused tier and through the XLA scan otherwise; values and
+    gradients agree."""
+    import dataclasses
+    import repro.models.mamba as M
+    from repro.configs import get_config
+    from repro.core import DoRAConfig
+    from repro.core.dispatch import plan_scan
+    from repro.models import init_params
+    for var in ("REPRO_FORCE_TIER", "REPRO_DORA_MODE", "REPRO_DORA_FUSED"):
+        monkeypatch.delenv(var, raising=False)
+    mcfg = dataclasses.replace(get_config("jamba2-3b", smoke=True),
+                               d_model=64)
+    assert mcfg.d_inner == 128
+    dcfg = DoRAConfig(rank=4, force_tier=tier)
+    assert plan_scan(dcfg, d_inner=mcfg.d_inner).fused is fused
+    called = []
+    real = M.selective_scan
+    monkeypatch.setattr(M, "selective_scan",
+                        lambda *a, **kw: called.append(1) or real(*a, **kw))
+    params = init_params(jax.random.PRNGKey(0), mcfg)
+    p = jax.tree.map(lambda t: t[0],
+                     params["stack"]["l0"])["mixer"]
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 24, 64), _F32)
+
+    def out(dcfg):
+        return jax.value_and_grad(lambda x: jnp.sum(jnp.sin(
+            M.mamba_block(x, p, None, mcfg, dcfg)[0])))(x)
+    v, g = out(dcfg)
+    assert bool(called) is fused
+    v0, g0 = out(DoRAConfig(rank=4, force_tier="eager"))
+    np.testing.assert_allclose(float(v), float(v0), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(g), np.asarray(g0), rtol=1e-4,
+                               atol=1e-5)
+
+
+def test_training_core_in_blocks_equals_one_pass(monkeypatch):
+    """Training over more than SEQ_BLOCK tokens runs the mixer's core in
+    checkpointed blocks that carry the state and the conv's inputs: the
+    output and the input gradient equal one pass over the sequence."""
+    import dataclasses
+    import repro.models.mamba as M
+    from repro.configs import get_config
+    from repro.models import init_params
+    mcfg = dataclasses.replace(get_config("jamba2-3b", smoke=True),
+                               d_model=64)
+    params = init_params(jax.random.PRNGKey(0), mcfg)
+    p = jax.tree.map(lambda t: t[0], params["stack"]["l0"])["mixer"]
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 24, 64), jnp.float32)
+
+    def run():
+        return jax.value_and_grad(lambda x: jnp.sum(jnp.sin(
+            M.mamba_block(x, p, None, mcfg, None)[0])))(x)
+    v_one, g_one = run()
+    monkeypatch.setattr(M, "SEQ_BLOCK", 8)
+    called = []
+    real = M._core_in_blocks
+    monkeypatch.setattr(M, "_core_in_blocks",
+                        lambda *a: called.append(1) or real(*a))
+    v_blk, g_blk = run()
+    assert called
+    np.testing.assert_allclose(float(v_blk), float(v_one), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(g_blk), np.asarray(g_one),
+                               rtol=1e-4, atol=1e-5)

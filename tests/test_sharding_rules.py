@@ -90,7 +90,8 @@ class TestLeafRoles:
 
 
 @pytest.mark.parametrize("arch", ["qwen3-32b", "jamba-v0.1-52b",
-                                  "qwen2-moe-a2.7b", "falcon-mamba-7b"])
+                                  "qwen2-moe-a2.7b", "falcon-mamba-7b",
+                                  "jamba2-3b"])
 def test_param_sharding_tree_matches_shapes(arch):
     mcfg = get_config(arch)
     mesh = make_debug_mesh(1, 1)
@@ -112,6 +113,16 @@ def test_adapter_sharding_congruent(arch):
     shardings = S.adapter_sharding(mcfg, dcfg, mesh)
     assert (jax.tree.structure(shapes)
             == jax.tree.structure(shardings))
+
+
+@pytest.mark.parametrize("leaf", ["dt_norm", "b_norm", "c_norm"])
+def test_jamba_inner_norm_scales_are_replicated(leaf):
+    """Jamba's dt/B/C norm scales span dt_rank or the state size, which no
+    model axis splits: replicated on every mesh."""
+    mcfg = get_config("jamba2-3b")
+    assert mcfg.ssm_inner_norm
+    for mesh in (PROD, SINGLE):
+        assert S.leaf_roles(mcfg, leaf, 1, mesh) == ("repl",)
 
 
 def test_adapter_tp_congruence_rules():

@@ -24,6 +24,8 @@ from repro.kernels import dora_compose as ck
 from repro.kernels.factored_norm import norm_terms_pallas
 from repro.kernels.norm_assembly import assemble_norm_pallas
 from repro.kernels.paged_gather import paged_gather
+from repro.kernels.selective_scan import (selective_scan_bwd_pallas,
+                                          selective_scan_pallas)
 
 D_MODEL, D_FF, KV_HEADS, HEAD_DIM, RANK = 3584, 18944, 4, 128, 384
 ROWS = 4096            # one 4k training sequence
@@ -108,6 +110,36 @@ def test_compose_mm_bwd(one_chip):
         one_chip, ((ROWS, D_MODEL), BF16), ((D_MODEL, RANK), BF16),
         ((1, D_MODEL), F32), ((1, D_MODEL), F32))
     _assert_kernel(text, "compose_mm_bwd_pallas")
+
+
+# Jamba2-3B's selective scan: d_inner 5120, state 16, one 4096-token block
+# of the mixer's training core (``models.mamba.SEQ_BLOCK``), chunk 128.
+SCAN_DI, SCAN_N, SCAN_CHUNK = 5120, 16, 128
+
+
+def test_selective_scan_fwd(one_chip):
+    rows = ((1, ROWS, SCAN_DI), F32)
+    text = _compiled_text(
+        lambda dt, dtx, b, c, a, h0: selective_scan_pallas(
+            dt, dtx, b, c, a, h0, chunk=SCAN_CHUNK, save_states=True,
+            interpret=False),
+        one_chip, rows, rows, ((1, ROWS, SCAN_N), F32),
+        ((1, ROWS, SCAN_N), F32), ((SCAN_N, SCAN_DI), F32),
+        ((1, SCAN_N, SCAN_DI), F32))
+    _assert_kernel(text, "selective_scan_pallas")
+
+
+def test_selective_scan_bwd(one_chip):
+    rows = ((1, ROWS, SCAN_DI), F32)
+    state = ((1, SCAN_N, SCAN_DI), F32)
+    text = _compiled_text(
+        lambda dt, dtx, b, c, a, hs, dy, dh: selective_scan_bwd_pallas(
+            dt, dtx, b, c, a, hs, dy, dh, chunk=SCAN_CHUNK,
+            interpret=False),
+        one_chip, rows, rows, ((1, ROWS, SCAN_N), F32),
+        ((1, ROWS, SCAN_N), F32), ((SCAN_N, SCAN_DI), F32),
+        ((1, ROWS // SCAN_CHUNK, SCAN_N, SCAN_DI), F32), rows, state)
+    _assert_kernel(text, "selective_scan_bwd_pallas")
 
 
 def test_norm_terms(one_chip):
