@@ -40,6 +40,8 @@ def _reference(dt, xi, Bm, Cm, A, h0):
     (2, 16, 256, 16, 128, 8),   # di tiled 2x
     (1, 32, 128, 8, 128, 32),   # single chunk
     (2, 12, 128, 16, 128, 4),   # 3 chunks
+    (1, 256, 1024, 16, 512, 128),    # 2 chunks of 16 groups x 2 tiles
+    (1, 256, 2048, 16, 1024, 128),   # the same at the default tile width
 ])
 def test_kernel_matches_reference(B, S, di, n, block_di, chunk):
     dt, xi, Bm, Cm, A, h0 = _inputs(jax.random.PRNGKey(0), B, S, di, n)
@@ -107,11 +109,18 @@ def _sequential(dt, dtx, Bm, Cm, A, h0):
     return jnp.moveaxis(ys, 0, 1), h_f
 
 
-@pytest.mark.parametrize("B,S,di,chunk", [
-    (1, 32, 256, 8),     # 4 chunks, 2 d_inner tiles
-    (2, 20, 128, 8),     # S padded to 24 with no-op tokens
+@pytest.mark.parametrize("B,S,di,chunk,block_di", [
+    pytest.param(1, 32, 256, 8, 128, id="1-32-256-8"),   # 4 chunks, 2 tiles
+    pytest.param(2, 20, 128, 8, 128,      # S padded to 24 with no-op tokens
+                 id="2-20-128-8"),
+    pytest.param(1, 12, 128, 4, 128,      # chunks shorter than a group
+                 id="1-12-128-4"),
+    pytest.param(1, 256, 1024, 128, 512,  # 2 chunks of 16 groups, 2 tiles
+                 id="1-256-1024-128"),
+    pytest.param(1, 256, 2048, 128, 1024,  # the same at the default tile
+                 id="1-256-2048-128"),     # width
 ])
-def test_scan_vjp_matches_grad_of_sequential_scan(B, S, di, chunk):
+def test_scan_vjp_matches_grad_of_sequential_scan(B, S, di, chunk, block_di):
     """Forward and every cotangent of the custom-VJP kernel pair (dt, dtx,
     B, C, A, h0) against ``jax.grad`` of the sequential scan, with a
     nonzero h0 and cotangents on both y and h_final."""
@@ -129,7 +138,7 @@ def test_scan_vjp_matches_grad_of_sequential_scan(B, S, di, chunk):
             return jnp.sum(y * wy) + jnp.sum(h_f * wh)
         return f
 
-    kern = lambda *a: selective_scan(*a, chunk=chunk, block_di=128,
+    kern = lambda *a: selective_scan(*a, chunk=chunk, block_di=block_di,
                                      interpret=True)
     args = (dt, dtx, Bm, Cm, A, h0)
     (y_k, h_k), (y_r, h_r) = kern(*args), _sequential(*args)
